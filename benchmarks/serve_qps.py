@@ -1196,4 +1196,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

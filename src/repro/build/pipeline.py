@@ -171,11 +171,12 @@ def _encode_chunk(pts, centroids, codebook, counts, lo, hi, n_valid):
 def _merge_topk(best_s, best_i, queries, chunk_pts, base, n_valid, *, metric):
     """Fold one chunk into the calibration queries' running exact top-k.
 
-    Same internal score convention as ``core.ref.exact_topk`` (l2 drops
-    the |q|^2 rank-only term; internally higher-is-better), so the merged
-    ground-truth ids match the oracle's.
+    Same internal score convention and matmul precision as
+    ``core.ref.exact_topk`` (l2 drops the |q|^2 rank-only term; internally
+    higher-is-better), so the merged ground-truth ids match the oracle's.
     """
-    dots = queries @ chunk_pts.T                             # (Q, B)
+    dots = jnp.matmul(queries, chunk_pts.T,                  # (Q, B)
+                      precision=jax.lax.Precision.HIGHEST)
     if metric == "l2":
         p_sq = jnp.sum(chunk_pts * chunk_pts, axis=-1)
         scores = -(p_sq[None, :] - 2.0 * dots)

@@ -78,14 +78,17 @@ def padded_layout(labels: np.ndarray, n_clusters: int, cap: int
         ``(point_ids (C, P) int32 with -1 padding, labels (N,))``.
     """
     point_ids = np.full((n_clusters, cap), -1, dtype=np.int32)
-    fill = np.zeros((n_clusters,), dtype=np.int64)
-    overflow = []
-    for pid, c in enumerate(labels):
-        if fill[c] < cap:
-            point_ids[c, fill[c]] = pid
-            fill[c] += 1
-        else:
-            overflow.append(pid)
+    # each cluster takes its first ``cap`` points in id order (a stable sort
+    # by label gives every point its rank within its cluster); the rest
+    # overflow, in id order
+    order = np.argsort(labels, kind="stable")
+    by_label = np.asarray(labels)[order]
+    rank = np.arange(order.size) - np.searchsorted(by_label, by_label)
+    keep = rank < cap
+    point_ids[by_label[keep], rank[keep]] = order[keep]
+    fill = np.minimum(np.bincount(by_label, minlength=n_clusters),
+                      cap).astype(np.int64)
+    overflow = np.sort(order[~keep]).tolist()
     if overflow:  # spill to emptiest clusters (keeps every point searchable)
         order = np.argsort(fill)
         oi = 0

@@ -229,7 +229,7 @@ def _calibrate_density(pts, residuals, codebook, codes, ivf, config, key):
                                  degree=config.poly_degree)
 
 
-def _rt_probe_mask(rt_grid, q, tau, cids, rt_scale, rt_offset):
+def _rt_probe_mask(rt_grid, q, tau, cids, rt_scale, rt_offset, impl):
     """Stage-1 spatial pruning: which probed clusters survive the RT test.
 
     Runs the sphere-intersection filter (``repro.rt``) with the radius
@@ -238,20 +238,32 @@ def _rt_probe_mask(rt_grid, q, tau, cids, rt_scale, rt_offset):
     cluster ids (offset by ``rt_offset`` on a shard — the grid is global).
     Probe 0 is always kept (nearest-probe backstop), so a query whose
     sphere misses everything still degrades to a nprobe=1 search instead
-    of returning sentinels. Returns (Q, nprobe) bool.
+    of returning sentinels. ``impl`` picks the sphere-test path
+    (``kernels.ops.rt_sphere_hits``). Returns (Q, nprobe) bool.
     """
     from repro import rt as rt_lib
     radius = rt_lib.query_radius(rt_grid, tau[:, 0, :], rt_scale)
-    hits = rt_lib.survivor_mask(rt_grid, q, radius)          # (Q, C_global)
+    hits = rt_lib.survivor_mask(rt_grid, q, radius,          # (Q, C_global)
+                                impl=impl)
     gcids = cids if rt_offset is None else cids + rt_offset
     probe_ok = jnp.take_along_axis(hits, gcids, axis=1) > 0
     return probe_ok.at[:, 0].set(True)
 
 
+def _resolve_impl(impl: str | None) -> str:
+    """The search path for ``impl``; ``None`` follows the platform
+    (``kernels.ops.default_impl``: "pallas" on a TPU, "ref" elsewhere).
+    Called once, by the shared scoring tails every entry point reaches."""
+    if impl is None:
+        from repro.kernels.ops import default_impl
+        return default_impl()
+    return impl
+
+
 def _score_probed(index: JunoIndexData, q: jnp.ndarray, base: jnp.ndarray,
                   cids: jnp.ndarray, codes: jnp.ndarray, valid: jnp.ndarray,
                   ids: jnp.ndarray, *, k: int, mode: str, metric: str,
-                  thres_scale: float, impl: str = "ref",
+                  thres_scale: float, impl: str | None = None,
                   side: SideBuffer | None = None, prefilter: str = "scan",
                   rt_grid=None, rt_scale: float = 1.0, rt_offset=None):
     """Stages B+C over an explicitly gathered probe set.
@@ -265,8 +277,10 @@ def _score_probed(index: JunoIndexData, q: jnp.ndarray, base: jnp.ndarray,
     the paged backend (``repro.serve.paged``) gathers codes on the host
     through its cluster cache and feeds them in, so both paths share this
     scoring math verbatim. Only ``index.codebook`` and ``index.density``
-    are read from ``index``. Returns (scores (Q, k), ids (Q, k)).
+    are read from ``index``. ``impl=None`` follows the platform
+    (:func:`_resolve_impl`). Returns (scores (Q, k), ids (Q, k)).
     """
+    impl = _resolve_impl(impl)
     nq = q.shape[0]
     nprobe = cids.shape[1]
     m = index.codebook.sub_dim
@@ -284,7 +298,8 @@ def _score_probed(index: JunoIndexData, q: jnp.ndarray, base: jnp.ndarray,
 
     # --- stage C: distance calculation over the selected clusters --------
     if prefilter == "rt":
-        probe_ok = _rt_probe_mask(rt_grid, q, tau, cids, rt_scale, rt_offset)
+        probe_ok = _rt_probe_mask(rt_grid, q, tau, cids, rt_scale,
+                                  rt_offset, impl)
         valid = valid & probe_ok[..., None]
 
     if impl == "pallas":
@@ -363,13 +378,14 @@ def _score_probed(index: JunoIndexData, q: jnp.ndarray, base: jnp.ndarray,
                                     "prefilter"))
 def _search_batch(index: JunoIndexData, queries: jnp.ndarray, *, nprobe: int,
                   k: int, mode: str, metric: str, thres_scale: float,
-                  impl: str = "ref", side: SideBuffer | None = None,
+                  impl: str | None = None, side: SideBuffer | None = None,
                   prefilter: str = "scan", rt_grid=None,
                   rt_scale: float = 1.0, rt_offset=None):
     """One jitted query batch. Returns (scores (Q,k), ids (Q,k)).
 
     impl="ref"    — pure-jnp reference path (semantics of record)
     impl="pallas" — fused Pallas kernels (TPU path; interpret=True on CPU)
+    impl=None     — follows the platform (:func:`_resolve_impl`)
     side          — optional overflow buffer of online inserts, merged into
                     the final top-k with in-cluster-identical scoring.
     prefilter     — "scan" (dense, every probed cluster scanned) or "rt"
@@ -396,7 +412,7 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
                             codes: jnp.ndarray, valid: jnp.ndarray,
                             ids: jnp.ndarray, *, k: int, metric: str,
                             thres_scale: float, rerank: int = 0,
-                            impl: str = "ref", fused: bool = False,
+                            impl: str | None = None, fused: bool = False,
                             fused3: bool | None = None,
                             side: SideBuffer | None = None,
                             prefilter: str = "scan", rt_grid=None,
@@ -413,8 +429,10 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
     top-C candidates WITH their masked-ADC distances, so this function does
     no wide top-k and no separate rerank gather. Candidate selection is the
     same top-C-by-count rule, so fused and composed return identical ids
-    (tests/test_impl_parity.py). Orthogonal to ``impl``, which picks who
-    builds the LUT/hit tables.
+    (tests/test_impl_parity.py). ``impl`` picks who builds the LUT/hit
+    tables and, on a TPU, whether the fused scan runs its Pallas kernels
+    ("pallas") or the jnp host path ("ref"); ``None`` follows the platform
+    (:func:`_resolve_impl`).
 
     When ``fused=True`` meets ``prefilter="rt"``, the RT sphere test ALSO
     folds in — ``kernels.ops.fused_three_stage_scan`` runs the sphere
@@ -430,6 +448,7 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
     ``ids`` arrive pre-gathered so the resident and paged
     (``repro.serve.paged``) backends share the scoring math verbatim.
     """
+    impl = _resolve_impl(impl)
     nq = q.shape[0]
     nprobe = cids.shape[1]
     m = index.codebook.sub_dim
@@ -447,7 +466,8 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
 
     use_fused3 = fused and prefilter == "rt" and fused3 is not False
     if prefilter == "rt" and not use_fused3:
-        probe_ok = _rt_probe_mask(rt_grid, q, tau, cids, rt_scale, rt_offset)
+        probe_ok = _rt_probe_mask(rt_grid, q, tau, cids, rt_scale,
+                                  rt_offset, impl)
         valid = valid & probe_ok[..., None]
 
     from repro.kernels import ops as kops
@@ -469,9 +489,9 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
     p = codes.shape[2]
     cap = min(c_budget, nprobe * p)
     if use_fused3:
-        # all three stages in one residency: the kernel runs the sphere
-        # walk over the grid cells, masks the probes in-register (same
-        # slot_of verdicts _rt_probe_mask would gather, probe 0
+        # all three stages in the fused scan: the counts kernel runs the
+        # sphere test of each probed cluster, masks the probes in-register
+        # (same slot_of verdicts _rt_probe_mask would gather, probe 0
         # backstopped), then counts, thresholds and compacts as the fused
         # two-stage scan does — no HBM hit table, no host mask round trip
         from repro import rt as rt_lib
@@ -481,18 +501,17 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
         slot_idx = jnp.take(rt_grid.slot_of, gcids)              # (Q, np)
         _, _, cand, exact, probe_ok = kops.fused_three_stage_scan(
             mlut, table, codes, valid, qp2[:, 0], qp2[:, 1], radius,
-            rt_grid.boxes, rt_grid.cell_reach, rt_grid.cell_c0,
-            rt_grid.cell_c1, rt_grid.slot_reach, slot_idx,
-            cap_c=cap, metric=metric)
+            rt_grid.cell_c0, rt_grid.cell_c1, rt_grid.slot_reach, slot_idx,
+            cap_c=cap, metric=metric, impl=impl)
         valid = valid & probe_ok[..., None]
         cand_probe = cand // p                                   # (Q, C)
         cand_valid = jnp.take_along_axis(valid.reshape(nq, -1), cand, axis=1)
         cand_ids = jnp.take_along_axis(ids.reshape(nq, -1), cand, axis=1)
     elif fused:
-        # both stages in one fused scan: counts, in-kernel survivor
-        # threshold, compacted top-C candidates + their ADC totals
+        # both stages in one fused scan: counts, survivor threshold,
+        # compacted top-C candidates + their ADC totals
         _, _, cand, exact = kops.fused_two_stage_scan(
-            mlut, table, codes, valid, cap_c=cap, metric=metric)
+            mlut, table, codes, valid, cap_c=cap, metric=metric, impl=impl)
         cand_probe = cand // p                                   # (Q, C)
         cand_valid = jnp.take_along_axis(valid.reshape(nq, -1), cand, axis=1)
         cand_ids = jnp.take_along_axis(ids.reshape(nq, -1), cand, axis=1)
@@ -553,7 +572,7 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
 def _search_batch_two_stage(index: JunoIndexData, queries: jnp.ndarray, *,
                             nprobe: int, k: int, metric: str,
                             thres_scale: float, rerank: int = 0,
-                            impl: str = "ref", fused: bool = False,
+                            impl: str | None = None, fused: bool = False,
                             fused3: bool | None = None,
                             side: SideBuffer | None = None,
                             prefilter: str = "scan", rt_grid=None,
@@ -577,7 +596,8 @@ def _search_batch_two_stage(index: JunoIndexData, queries: jnp.ndarray, *,
 
 def search(index: JunoIndexData, queries: jnp.ndarray, *, nprobe: int = 16,
            k: int = 100, mode: str = "H", metric: str = "l2",
-           thres_scale: float = 1.0, batch: int = 64, impl: str = "ref",
+           thres_scale: float = 1.0, batch: int = 64,
+           impl: str | None = None,
            rerank: int = 0, fused: bool = False, fused3: bool | None = None,
            side: SideBuffer | None = None, prefilter: str = "scan",
            rt_grid=None, rt_scale: float = 1.0):
@@ -607,17 +627,18 @@ def search(index: JunoIndexData, queries: jnp.ndarray, *, nprobe: int = 16,
         Multiplier on the calibrated selectivity thresholds τ.
     batch : int
         Queries per jitted call (one compiled program per distinct batch).
-    impl : str
+    impl : str, optional
         "ref" (pure-jnp semantics of record) or "pallas" (TPU kernels;
-        interpret mode off-TPU).
+        interpret mode off-TPU). ``None`` follows the platform
+        (``kernels.ops.default_impl``): "pallas" on a TPU, "ref" elsewhere.
     rerank : int
         Mode "H2" stage-2 candidate budget C (0 → ``4 * k``).
     fused : bool
         Mode "H2" only: serve both stages through the fused
         hit-count→masked-ADC kernel path; top-k ids are identical to the
         composed path (see ``_search_batch_two_stage``). Combined with
-        ``prefilter="rt"`` this dispatches the single-residency
-        three-stage kernel (RT walk folded in as stage 0) unless
+        ``prefilter="rt"`` this dispatches the three-stage kernels
+        (the probes' RT sphere test folded into the counts kernel) unless
         ``fused3=False``.
     fused3 : bool, optional
         Three-stage dispatch override. ``None`` (default) auto-selects it

@@ -9,6 +9,7 @@ vector replaces ``t_max`` (DESIGN.md §2).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .pq import PQCodebook
@@ -28,8 +29,11 @@ def build_lut(residual_sub: jnp.ndarray, codebook: PQCodebook, tau: jnp.ndarray,
           so selection still means "spatially close", while the LUT stores the
           similarity that will be accumulated — higher-is-better.)
     """
+    # HIGHEST: at default precision a TPU rounds the f32 operands of this
+    # contraction to bf16, and the reference would drift from the kernel
     r_dot_e = jnp.einsum("...sm,sem->...se", residual_sub,
-                         codebook.entries)                     # (..., S, E)
+                         codebook.entries,
+                         precision=jax.lax.Precision.HIGHEST)  # (..., S, E)
     e_sq = codebook.entry_sq                                    # (S, E)
     tau_sq = (tau * tau)[..., None]
     if metric == "l2":

@@ -14,6 +14,10 @@ def exact_topk(queries: jnp.ndarray, points: jnp.ndarray, *, k: int,
                metric: str = "l2", chunk: int = 65536):
     """Exact top-k ids+scores. queries (Q, D), points (N, D) -> (Q, k) each.
 
+    The distance matmul runs at ``Precision.HIGHEST``: at default
+    precision a TPU rounds f32 operands to bf16 on the MXU, and the
+    ground truth would no longer be exact.
+
     Streaming top-k: scan over point chunks keeping the running best k, so
     memory is O(Q * (chunk + k)) regardless of N.
     """
@@ -28,7 +32,8 @@ def exact_topk(queries: jnp.ndarray, points: jnp.ndarray, *, k: int,
     def body(carry, xs):
         best_s, best_i = carry
         chunk_pts, base = xs
-        dots = q @ chunk_pts.T                                   # (Q, chunk)
+        dots = jnp.matmul(q, chunk_pts.T,                        # (Q, chunk)
+                          precision=jax.lax.Precision.HIGHEST)
         if metric == "l2":
             p_sq = jnp.sum(chunk_pts * chunk_pts, axis=-1)
             scores = -(p_sq[None, :] - 2.0 * dots)               # -(|p|^2-2qp)

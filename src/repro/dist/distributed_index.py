@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.density import DensityModel
@@ -33,6 +32,15 @@ def _cluster_entry(mesh: Mesh):
     """Shard the cluster dim over ALL mesh axes (pure scale-out)."""
     axes = tuple(mesh.axis_names)
     return axes if len(axes) > 1 else axes[0]
+
+
+def _shard_lin(mesh: Mesh) -> jnp.ndarray:
+    """Linear index of the calling shard over all mesh axes (row-major),
+    inside a ``shard_map`` body."""
+    lin = jnp.int32(0)
+    for ax in mesh.axis_names:
+        lin = lin * mesh.shape[ax] + jax.lax.axis_index(ax)
+    return lin
 
 
 def index_pspecs(mesh: Mesh) -> JunoIndexData:
@@ -89,7 +97,8 @@ def grid_pspecs():
 
 def make_distributed_search(mesh: Mesh, local_nprobe: int, k: int, *,
                             mode: str = "H", metric: str = "l2",
-                            thres_scale: float = 1.0, impl: str = "ref",
+                            thres_scale: float = 1.0,
+                            impl: str | None = None,
                             rerank: int = 0, fused: bool = False,
                             fused3: bool | None = None,
                             with_side: bool = False,
@@ -99,12 +108,13 @@ def make_distributed_search(mesh: Mesh, local_nprobe: int, k: int, *,
     ``local_nprobe`` is the probe budget PER SHARD (global work scales with
     the mesh, matching the paper's fixed per-chip scan cost). The returned
     callable is jitted, so ``dsearch.lower(...)`` works for the dry-run.
+    ``impl=None`` follows the platform, as in :func:`repro.core.juno.search`.
 
     ``fused=True`` (mode "H2" only) runs each shard's two-stage scan
     through the fused hit-count→masked-ADC kernel path — per-shard results,
     and therefore the exact global merge, are id-identical to the composed
     path (core/juno.py). Combined with ``prefilter="rt"`` each shard
-    serves the single-residency three-stage kernel (the shard's probes
+    serves the three-stage kernels (the shard's probes
     look up the replicated grid at ``local_cid + shard_offset`` inside
     the kernel, same offset rule as the composed path); ``fused3=False``
     forces the composed rt+fused baseline, bit-identically.
@@ -143,9 +153,7 @@ def make_distributed_search(mesh: Mesh, local_nprobe: int, k: int, *,
         side = rest.pop(0) if with_side else None
         rt_grid = rest.pop(0) if prefilter == "rt" else None
         n_local = idx.ivf.centroids.shape[0]
-        lin = jnp.int32(0)
-        for ax in axes:
-            lin = lin * mesh.shape[ax] + jax.lax.axis_index(ax)
+        lin = _shard_lin(mesh)
         if side is not None:
             side = side._replace(cluster=side.cluster - lin * n_local)
         rt_kw = {}
@@ -178,35 +186,61 @@ def make_distributed_search(mesh: Mesh, local_nprobe: int, k: int, *,
         in_specs = in_specs + (side_pspecs(),)
     if prefilter == "rt":
         in_specs = in_specs + (grid_pspecs(),)
-    fn = shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)
+    fn = jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     return jax.jit(fn)
+
+
+def _routed_update(mesh: Mesh, local_update):
+    """Jit a scatter into the cluster-sharded index, routed by cluster.
+
+    ``apply(idx, clusters, *rest)`` runs as a ``shard_map`` body: each
+    shard localizes the GLOBAL cluster ids to its own row range and
+    ``local_update(idx_local, local_clusters, *rest)`` scatters with
+    ``mode="drop"``, so rows owned by other shards (mapped to the
+    out-of-range row ``n_local``) are dropped. The update therefore lands
+    on the owning shard with no collective and no resharding, and the
+    output keeps the input layout — on meshes with Auto and Explicit axes
+    alike (a plain ``.at[].set`` on an Explicit mesh must name an output
+    sharding). ``clusters`` and ``rest`` are replicated.
+    """
+    specs = index_pspecs(mesh)
+
+    def body(idx: JunoIndexData, clusters, *rest):
+        n_local = idx.ivf.point_ids.shape[0]
+        local = clusters - _shard_lin(mesh) * n_local
+        local = jnp.where((local >= 0) & (local < n_local), local, n_local)
+        return local_update(idx, local, *rest)
+
+    def apply(idx: JunoIndexData, clusters, *rest):
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(specs,) + (P(),) * (1 + len(rest)),
+                           out_specs=specs, check_vma=False)
+        return fn(idx, clusters, *rest)
+
+    return jax.jit(apply)
 
 
 def make_distributed_insert(mesh: Mesh):
     """Jitted ``apply(idx, clusters, slots, ids, codes) -> idx`` scatter.
 
-    The scatter targets rows of the cluster-sharded arrays, so XLA routes
-    each update to the shard that owns the cluster — inserts are "routed by
-    owning cluster" with no resharding and no shape change (hot jitted
-    search signatures stay warm). Output shardings are pinned to the input
-    layout.
+    The scatter targets rows of the cluster-sharded arrays and runs on the
+    shard that owns each cluster (:func:`_routed_update`) — inserts are
+    "routed by owning cluster" with no resharding and no shape change (hot
+    jitted search signatures stay warm).
     """
-    specs = index_pspecs(mesh)
-    out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                          is_leaf=lambda x: isinstance(x, P))
-
-    def apply(idx: JunoIndexData, clusters, slots, ids, codes):
+    def update(idx: JunoIndexData, clusters, slots, ids, codes):
         """Scatter new (id, code) cells into their owning clusters."""
         ivf = idx.ivf._replace(
-            point_ids=idx.ivf.point_ids.at[clusters, slots].set(ids),
-            valid=idx.ivf.valid.at[clusters, slots].set(True))
+            point_ids=idx.ivf.point_ids.at[clusters, slots].set(
+                ids, mode="drop"),
+            valid=idx.ivf.valid.at[clusters, slots].set(True, mode="drop"))
         return idx._replace(
-            ivf=ivf,
-            cluster_codes=idx.cluster_codes.at[clusters, slots].set(codes))
+            ivf=ivf, cluster_codes=idx.cluster_codes.at[clusters, slots].set(
+                codes, mode="drop"))
 
-    return jax.jit(apply, out_shardings=out_sh)
+    return _routed_update(mesh, update)
 
 
 def make_distributed_row_update(mesh: Mesh):
@@ -214,39 +248,31 @@ def make_distributed_row_update(mesh: Mesh):
 
     The rebuild counterpart of :func:`make_distributed_insert`: instead of
     touching single (cluster, slot) cells it replaces ENTIRE padded rows
-    (point ids, valid mask and PQ codes) of the given clusters — XLA
-    routes each row to the shard owning it, so a per-shard rebuild is one
-    scatter with no resharding and no shape change.
+    (point ids, valid mask and PQ codes) of the given clusters, each on
+    the shard owning it — a per-shard rebuild is one scatter with no
+    resharding and no shape change.
     """
-    specs = index_pspecs(mesh)
-    out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                          is_leaf=lambda x: isinstance(x, P))
-
-    def apply(idx: JunoIndexData, clusters, row_ids, row_valid, row_codes):
+    def update(idx: JunoIndexData, clusters, row_ids, row_valid, row_codes):
         """Replace whole padded rows of the given clusters."""
         ivf = idx.ivf._replace(
-            point_ids=idx.ivf.point_ids.at[clusters].set(row_ids),
-            valid=idx.ivf.valid.at[clusters].set(row_valid))
+            point_ids=idx.ivf.point_ids.at[clusters].set(row_ids,
+                                                         mode="drop"),
+            valid=idx.ivf.valid.at[clusters].set(row_valid, mode="drop"))
         return idx._replace(
-            ivf=ivf,
-            cluster_codes=idx.cluster_codes.at[clusters].set(row_codes))
+            ivf=ivf, cluster_codes=idx.cluster_codes.at[clusters].set(
+                row_codes, mode="drop"))
 
-    return jax.jit(apply, out_shardings=out_sh)
+    return _routed_update(mesh, update)
 
 
 def make_distributed_delete(mesh: Mesh):
     """Jitted ``apply(idx, clusters, slots) -> idx`` tombstone scatter."""
-    specs = index_pspecs(mesh)
-    out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                          is_leaf=lambda x: isinstance(x, P))
-
-    def apply(idx: JunoIndexData, clusters, slots):
+    def update(idx: JunoIndexData, clusters, slots):
         """Clear the valid bit of the given (cluster, slot) cells."""
-        ivf = idx.ivf._replace(
-            valid=idx.ivf.valid.at[clusters, slots].set(False))
-        return idx._replace(ivf=ivf)
+        return idx._replace(ivf=idx.ivf._replace(
+            valid=idx.ivf.valid.at[clusters, slots].set(False, mode="drop")))
 
-    return jax.jit(apply, out_shardings=out_sh)
+    return _routed_update(mesh, update)
 
 
 class DistributedMutableIndex(MutableIndexBase):
@@ -283,13 +309,20 @@ class DistributedMutableIndex(MutableIndexBase):
         self._insert_fn = make_distributed_insert(mesh)
         self._delete_fn = make_distributed_delete(mesh)
         self._row_update_fn = make_distributed_row_update(mesh)
-        # replicated small arrays for insert-time encoding
-        self._centroids = idx.ivf.centroids
-        self._codebook = idx.codebook
+        self._replicate_encoder(idx)
         self._init_bookkeeping(idx.ivf.valid, idx.ivf.point_ids,
                                side_capacity=side_capacity,
                                first_new_id=int(idx.codes.shape[0]),
                                n_subspaces=int(idx.codes.shape[1]))
+
+    def _replicate_encoder(self, idx: JunoIndexData) -> None:
+        """Replicated copies of the small arrays insert-time encoding
+        reads. ``idx`` may come back from a rebuild with cluster-sharded
+        centroids; gathering rows of those on a mesh with Explicit axes
+        would need an output sharding, so they are replicated here."""
+        rep = NamedSharding(self.mesh, P())
+        self._centroids = jax.device_put(idx.ivf.centroids, rep)
+        self._codebook = jax.device_put(idx.codebook, rep)
 
     def _labels_codes(self, pts):
         return _label_encode(pts, self._centroids, self._codebook)
@@ -351,8 +384,7 @@ class DistributedMutableIndex(MutableIndexBase):
             int(np.asarray(new_data.ivf.point_ids).max(initial=-1)) + 1)
         self.data = shard_index(new_data, self.mesh)
         self.rt_grid = None
-        self._centroids = new_data.ivf.centroids
-        self._codebook = new_data.codebook
+        self._replicate_encoder(new_data)
         self._init_bookkeeping(
             new_data.ivf.valid, new_data.ivf.point_ids,
             side_capacity=(self.side.capacity if side_capacity is None

@@ -52,8 +52,10 @@ TOPC_IMPLS = ("sort", "topk")
 
 # canonical candidate axes — enumeration order is the deterministic
 # tie-break order, so keep these stable across releases
-_BQ = (2, 4, 8)
-_BP = (64, 128, 256)
+# (tiles the chip accepts: bq a multiple of 8 on the sublane axis, bp a
+# multiple of 128 on the lane axis — the kernels round other values up)
+_BQ = (8, 16)
+_BP = (128, 256)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,7 +66,7 @@ class KernelConfig:
     the host path — all four are result-invariant by construction.
     """
 
-    bq: int = 4
+    bq: int = 8
     bp: int | None = None
     topc_impl: str = "sort"
     acc_dtype: str = "f32"
@@ -159,19 +161,13 @@ def _three_stage_problem(seed: int = 0):
     q0 = jnp.asarray(rng.normal(size=(q,)), jnp.float32)
     q1 = jnp.asarray(rng.normal(size=(q,)), jnp.float32)
     radius = jnp.asarray(rng.random(size=(q,)), jnp.float32)
-    boxes = jnp.asarray(
-        np.stack([rng.normal(size=n_cells) - 2.0,
-                  rng.normal(size=n_cells) - 2.0,
-                  rng.normal(size=n_cells) + 2.0,
-                  rng.normal(size=n_cells) + 2.0], axis=1), jnp.float32)
     c0 = jnp.asarray(rng.normal(size=(n_cells, cap)), jnp.float32)
     c1 = jnp.asarray(rng.normal(size=(n_cells, cap)), jnp.float32)
     reach = jnp.asarray(np.abs(rng.normal(size=(n_cells, cap))), jnp.float32)
-    cell_reach = reach.max(axis=1)
     slot_idx = jnp.asarray(
         rng.integers(0, n_cells * cap, size=(q, n_probe)), jnp.int32)
-    return (lut, table, codes, valid, q0, q1, radius, boxes, cell_reach,
-            c0, c1, reach, slot_idx, cap_c)
+    return (lut, table, codes, valid, q0, q1, radius, c0, c1, reach,
+            slot_idx, cap_c)
 
 
 def _run_fn(kernel: str, config: KernelConfig, problem):
@@ -189,13 +185,13 @@ def _run_fn(kernel: str, config: KernelConfig, problem):
             lut, table, codes, valid, cap_c=cap_c,
             topc_impl=config.topc_impl)
     if kernel == "fused_three_stage":
-        (lut, table, codes, valid, q0, q1, radius, boxes, cell_reach,
-         c0, c1, reach, slot_idx, cap_c) = problem
+        (lut, table, codes, valid, q0, q1, radius, c0, c1, reach, slot_idx,
+         cap_c) = problem
         if on_tpu:
             return lambda: _f3.fused_three_stage(
-                lut, table, codes, valid, q0, q1, radius, boxes, cell_reach,
-                c0, c1, reach, slot_idx, cap_c=cap_c, bq=config.bq,
-                bp=config.bp, acc=config.acc_dtype)
+                lut, table, codes, valid, q0, q1, radius, c0, c1, reach,
+                slot_idx, cap_c=cap_c, bq=config.bq, bp=config.bp,
+                acc=config.acc_dtype)
         return lambda: _f3.fused_three_stage_host(
             lut, table, codes, valid, q0, q1, radius, c0, c1, reach,
             slot_idx, cap_c=cap_c, topc_impl=config.topc_impl)

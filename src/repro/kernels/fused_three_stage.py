@@ -1,48 +1,28 @@
-"""Pallas TPU kernel: single-residency three-stage search — RT sphere test,
-int8 hit-count prefilter, and survivor-masked ADC in ONE kernel (paper §5.5
-+ §6; DESIGN.md §2-§3; ROADMAP open item 2).
+"""Pallas TPU kernels: three-stage search — RT sphere test, int8 hit-count
+prefilter and survivor-masked ADC (paper §5.5 + §6; DESIGN.md §2-§3).
 
 The paper's full hardware pipeline maps the RT-core sphere test *into* the
 tensor-core distance stage: survivors of the BVH traversal stream straight
-to the MXU without a host-visible round trip. Before this kernel the repo
-paid exactly that round trip — ``rt_sphere_hits`` produced an HBM-resident
-(Q, n_cells·cap) table, the host gathered it into a probe mask, and the
-mask re-entered ``fused_two_stage`` as a pre-masked ``valid``. Here the
-whole thing is one ``pallas_call`` with grid (Q/bQ, 3, J),
-J = max(n_cells, np·Ppad/bP):
+to the distance stage without a host-visible round trip. The composed path
+pays that round trip — ``rt_sphere_hits`` produces an HBM-resident
+(Q, n_cells·cap) table, the host gathers it into a probe mask, and the mask
+re-enters ``fused_two_stage`` as a pre-masked ``valid``. Here the sphere
+test is stage 0 of the two-stage counts kernel (``fused_two_stage.
+two_stage``): each (query-block, point-block) tile belongs to one probe,
+and the kernel tests the block's query discs against that probed cluster's
+projected centroid and reach (gathered from the ``CentroidGrid`` layout at
+``slot_idx``) before counting. The hit table never materializes: only the
+probes a query actually scans are tested, so the cell walk and its AABB
+skip have nothing left to prune.
 
-  phase 0 (grid t=0) — the RT walk of ``rt/intersect.py``, one cell per
-      program: AABB pre-test gates the per-slot disc-vs-disc tests behind
-      ``pl.when`` (the BVH-subtree skip), and each live cell's verdicts are
-      merged *directly into a (bQ, np) probe-ok scratch* via the probed
-      clusters' flat slot indices (``CentroidGrid.slot_of[cids]``) — the
-      hit table never materializes, in VMEM or anywhere else.
-  phase 1 (grid t=1) — the hit-count pass of ``fused_two_stage``, with
-      ``valid`` masked in-register by the phase-0 scratch (probe 0 is
-      backstopped exactly like ``_rt_probe_mask``), plus the streamed
-      per-query top-``cap_c`` threshold carried in VMEM scratch.
-  phase 2 (grid t=2) — the survivor-masked ADC + per-block candidate
-      compaction, unchanged from the two-stage kernel.
-
-Because the cell axis (phase 0) and the point-block axis (phases 1-2) are
-both folded onto grid axis 2 of length J, programs past their own axis
-clamp their block index and re-run idempotent work: phase-0 programs with
-j ≥ n_cells redo cell n_cells-1's merge (same values → same scratch), and
-phase-1 programs with j ≥ np·Ppad/bP rewrite block np·Ppad/bP - 1 but are
-fenced out of the streamed top-C merge (``pl.when(j < npmax)``) so no
-duplicate entries can enter the running selection.
-
-Outputs are the two-stage kernel's four (bit-identical to composing
+Outputs are the two-stage kernel's four (equal to composing
 ``rt_sphere_hits`` → probe-mask gather → ``fused_two_stage``; pinned by
-tests/test_fused3_kernel.py) plus ``probe_ok`` (Q, np) bool — the phase-0
+tests/test_fused3_kernel.py) plus ``probe_ok`` (Q, np) bool — the stage-0
 verdict per probed cluster, identical to ``core.juno._rt_probe_mask`` —
 so the side-buffer/minor-tier path downstream applies the SAME verdict to
 out-of-cluster points as the kernel applied to their in-cluster siblings.
-
-VMEM per program adds to the two-stage budget only the cell operands and
-the probe scratch: 4·cap·4 [boxes+planes+reach] + bQ·np·4 [probe-ok] +
-bQ·np·4 [slot idx] ≈ 18 KB at (cap, np) = (64, 32) — the ≈2.6 MB
-(bQ, bP, S, E, C) = (4, 128, 48, 256, 400) two-stage budget dominates.
+Probe 0 is the nearest-probe backstop: its reach is set to +inf, so its
+disc test always passes, like ``_rt_probe_mask``'s ``.at[:, 0].set(True)``.
 
 ``fused_three_stage_host`` is the schedule-equivalent host path for
 off-TPU serving; ``kernels.ref.fused_three_stage_ref`` is the dense jnp
@@ -55,146 +35,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .fused_two_stage import (_INIT, _NEG, _PAD, SLAB, _largest_divisor,
-                              count_dot, fused_two_stage_host)
-from .ops import slab_onehot_dot
+from .fused_two_stage import (DEFAULT_BP, DEFAULT_BQ, fused_two_stage_host,
+                              two_stage)
 from .ref import rt_sphere_hits_ref
-
-DEFAULT_BQ = 4     # query rows per program
-DEFAULT_BP = 128   # points per program (upper bound; must divide P)
-
-
-def _fused3_kernel(q0_ref, q1_ref, r_ref, box_ref, creach_ref,
-                   c0_ref, c1_ref, reach_ref, sidx_ref,
-                   lut_ref, table_ref, codes_ref, valid_ref,
-                   counts_ref, dist_ref, cand_ref, cdist_ref, pok_ref,
-                   pok_s, topv_ref, topi_ref, *, n_entries, cap_c, bp,
-                   p_real, p_pad, bad_value, npb, n_cells, cap, acc):
-    t = pl.program_id(1)           # 0 = RT walk, 1 = hit-count, 2 = ADC
-    j = pl.program_id(2)           # cell index (t=0) / point-block (t=1,2)
-    n_probe = sidx_ref.shape[1]
-    npmax = n_probe * npb          # real point blocks (j clamps above this)
-
-    @pl.when(t == 0)
-    def _stage0():
-        @pl.when(j == 0)
-        def _init():
-            pok_s[...] = jnp.zeros_like(pok_s)
-        # cell AABB pre-test, verbatim from rt/intersect.py: a cell no
-        # query disc touches skips the slot tests AND the scratch merge
-        # (missed slots contribute 0, which is what the init left there)
-        q0 = q0_ref[...]                              # (bQ,)
-        q1 = q1_ref[...]
-        r = r_ref[...]
-        box = box_ref[...]                            # (1, 4) lo0 lo1 hi0 hi1
-        dx = jnp.clip(q0, box[0, 0], box[0, 2]) - q0
-        dy = jnp.clip(q1, box[0, 1], box[0, 3]) - q1
-        d2_cell = dx * dx + dy * dy
-        thr_cell = r + creach_ref[...][0]
-        live = (thr_cell >= 0.0) & (d2_cell <= thr_cell * thr_cell)
-
-        @pl.when(jnp.any(live))
-        def _slot_tests():
-            c0 = c0_ref[...][0]                       # (cap,)
-            c1 = c1_ref[...][0]
-            reach = reach_ref[...][0]
-            sx = q0[:, None] - c0[None, :]
-            sy = q1[:, None] - c1[None, :]
-            d2 = sx * sx + sy * sy
-            thr = r[:, None] + reach[None, :]
-            hit = ((thr >= 0.0) & (d2 <= thr * thr)).astype(jnp.int32)
-            # merge this cell's verdicts into the probe-ok scratch: probe
-            # slots whose flat slot index lives in THIS cell take their
-            # verdict from the (bQ, cap) hit tile. Each probe belongs to
-            # exactly one cell, so clamped duplicate programs (j >= n_cells
-            # re-runs cell n_cells-1) rewrite identical values.
-            jc = jnp.minimum(j, n_cells - 1)
-            sidx = sidx_ref[...]                      # (bQ, np) flat indices
-            in_cell = (sidx // cap) == jc
-            got = jnp.take_along_axis(hit, sidx % cap, axis=1)
-            pok_s[...] = jnp.where(in_cell, got, pok_s[...])
-
-        # placeholder writes: every output block this program maps to gets
-        # a defined value; phases 1-2 overwrite them all with finals
-        counts_ref[...] = jnp.zeros(counts_ref.shape, counts_ref.dtype)
-        dist_ref[...] = jnp.full(dist_ref.shape, bad_value, jnp.float32)
-        cand_ref[...] = jnp.zeros(cand_ref.shape, cand_ref.dtype)
-        cdist_ref[...] = jnp.full(cdist_ref.shape, bad_value, jnp.float32)
-        pok_ref[...] = jnp.zeros(pok_ref.shape, pok_ref.dtype)
-
-    @pl.when(t != 0)
-    def _scan_phases():
-        jp = jnp.minimum(j, npmax - 1)
-        probe = jp // npb
-        # in-register probe mask from the phase-0 scratch; probe 0 is
-        # backstopped exactly like _rt_probe_mask's `.at[:, 0].set(True)`
-        keep_q = (pok_s[...][:, probe] > 0) | (probe == 0)
-        codes = codes_ref[...].astype(jnp.int32)      # (bQ, bP, S)
-        valid = valid_ref[...] & keep_q[:, None]
-        cnt = count_dot(codes, table_ref[...][:, 0], n_entries=n_entries,
-                        acc=acc)
-        bad_count = _NEG
-        if p_pad != p_real:        # point axis padded: mark pad slots
-            lane = jp * bp + jax.lax.broadcasted_iota(
-                jnp.int32, (codes.shape[0], bp), 1)
-            bad_count = jnp.where(lane % p_pad < p_real, _NEG, _PAD)
-        counts = jnp.where(valid, cnt, bad_count)
-        counts_ref[...] = counts
-        iot = jax.lax.broadcasted_iota(jnp.int32, pok_ref.shape, 1)
-        pok_ref[...] = ((pok_s[...] > 0) | (iot == 0)).astype(jnp.int8)
-
-        @pl.when(t == 1)
-        def _stage1():
-            @pl.when(j == 0)
-            def _init():
-                topv_ref[...] = jnp.full_like(topv_ref, _INIT)
-                topi_ref[...] = jnp.zeros_like(topi_ref)
-
-            # streamed top-C merge, fenced to REAL point blocks: clamped
-            # duplicate programs (j >= npmax when the cell axis is longer)
-            # must not re-merge block npmax-1 or its entries would repeat
-            # in the running selection
-            @pl.when(j < npmax)
-            def _merge():
-                newi = jp * bp + jax.lax.broadcasted_iota(
-                    jnp.int32, counts.shape, 1)
-                runv = jnp.concatenate([topv_ref[...], counts], axis=1)
-                runi = jnp.concatenate([topi_ref[...], newi], axis=1)
-                v, pos = jax.lax.top_k(runv, cap_c)
-                topv_ref[...] = v
-                topi_ref[...] = jnp.take_along_axis(runi, pos, axis=1)
-            cand_ref[...] = topi_ref[...]
-            cdist_ref[...] = jnp.full_like(cdist_ref, bad_value)
-            dist_ref[...] = jnp.full(counts.shape, bad_value, jnp.float32)
-
-        @pl.when(t == 2)
-        def _stage2():
-            theta = topv_ref[...][:, cap_c - 1]       # (bQ,) survivor floor
-            keep = valid & (counts >= theta[:, None])
-            cand_ref[...] = topi_ref[...]
-            any_keep = jnp.any(keep)
-
-            @pl.when(any_keep)
-            def _adc():
-                lut = lut_ref[...][:, 0]              # (bQ, S, E) f32
-                adc = slab_onehot_dot(codes, lut, n_entries=n_entries,
-                                      out_dtype=jnp.float32, slab=SLAB)
-                dist = jnp.where(keep, adc, bad_value)
-                dist_ref[...] = dist
-                # compaction fold: this block's slice of the candidates
-                local = topi_ref[...] - jp * bp       # (bQ, C)
-                inblk = (local >= 0) & (local < bp)
-                got = jnp.take_along_axis(dist, jnp.clip(local, 0, bp - 1),
-                                          axis=1)
-                cdist_ref[...] = jnp.where(inblk, got, cdist_ref[...])
-
-            @pl.when(jnp.logical_not(any_keep))
-            def _skip():
-                dist_ref[...] = jnp.full(counts.shape, bad_value,
-                                         jnp.float32)
 
 
 @functools.partial(jax.jit,
@@ -203,105 +47,26 @@ def _fused3_kernel(q0_ref, q1_ref, r_ref, box_ref, creach_ref,
 def fused_three_stage(lut: jnp.ndarray, table: jnp.ndarray,
                       codes: jnp.ndarray, valid: jnp.ndarray,
                       q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
-                      boxes: jnp.ndarray, cell_reach: jnp.ndarray,
                       cell_c0: jnp.ndarray, cell_c1: jnp.ndarray,
                       slot_reach: jnp.ndarray, slot_idx: jnp.ndarray, *,
                       cap_c: int, metric: str = "l2", bq: int = DEFAULT_BQ,
-                      bp: int | None = None, acc: str = "f32",
+                      bp: int | None = DEFAULT_BP, acc: str = "f32",
                       interpret: bool = False):
     """lut/table (Q, np, S, E) f32/int8, codes (Q, np, P, S) uint8,
     valid (Q, np, P) bool; q0/q1/radius (Q,) f32 ray-plane queries;
-    boxes (n_cells, 4), cell_reach (n_cells,), cell_c0/cell_c1/slot_reach
-    (n_cells, cap) — the ``CentroidGrid`` layout; slot_idx (Q, np) int32 =
-    ``grid.slot_of[probed cluster ids]`` →
-    (counts (Q, np, P) i32, dist (Q, np, P) f32, cand (Q, C) i32,
-     cand_dist (Q, C) f32, probe_ok (Q, np) bool). See module docstring.
+    cell_c0/cell_c1/slot_reach (n_cells, cap) — the ``CentroidGrid``
+    layout; slot_idx (Q, np) int32 = ``grid.slot_of[probed cluster ids]``
+    → (counts (Q, np, P) i32, dist (Q, np, P) f32, cand (Q, C) i32,
+    cand_dist (Q, C) f32, probe_ok (Q, np) bool). See module docstring.
     ``bq``/``bp``/``acc`` are the autotuner's knobs — all
     result-invariant."""
-    q, n_probe, p, s = codes.shape
-    e = lut.shape[-1]
-    n_cells, cap = cell_c0.shape
-    cap_c = max(1, min(cap_c, n_probe * p))
-    bp = _largest_divisor(p, bp or DEFAULT_BP)
-    if bp < min(64, p):
-        # divisor cliff: pad the point axis per probe to a full tile; pad
-        # slots carry the below-_NEG _PAD sentinel (see fused_two_stage)
-        bp = DEFAULT_BP
-        pad_p = (-p) % bp
-        codes = jnp.pad(codes, ((0, 0), (0, 0), (0, pad_p), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, 0), (0, pad_p)))
-    p_pad = codes.shape[2]
-    w = n_probe * p_pad
-    bq = min(bq, q)
-    pad_q = (-q) % bq
-    if pad_q:
-        lut = jnp.pad(lut, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        table = jnp.pad(table, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        codes = jnp.pad(codes, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        valid = jnp.pad(valid, ((0, pad_q), (0, 0), (0, 0)))
-        q0 = jnp.pad(q0, (0, pad_q))
-        q1 = jnp.pad(q1, (0, pad_q))
-        radius = jnp.pad(radius, (0, pad_q))
-        slot_idx = jnp.pad(slot_idx, ((0, pad_q), (0, 0)))
-    qp = q + pad_q
-    codes_f = codes.reshape(qp, w, s)
-    valid_f = valid.reshape(qp, w)
-    npb = p_pad // bp                 # point blocks per probe
-    npmax = n_probe * npb
-    jdim = max(n_cells, npmax)        # shared cell/point-block grid axis
-    bad = float("inf") if metric == "l2" else float("-inf")
-    jc = lambda j: jnp.minimum(j, n_cells - 1)          # noqa: E731
-    jp = lambda j: jnp.minimum(j, npmax - 1)            # noqa: E731
-
-    counts, dist, cand, cdist, pok = pl.pallas_call(
-        functools.partial(_fused3_kernel, n_entries=e, cap_c=cap_c, bp=bp,
-                          p_real=p, p_pad=p_pad, bad_value=bad, npb=npb,
-                          n_cells=n_cells, cap=cap, acc=acc),
-        grid=(qp // bq, 3, jdim),
-        in_specs=[
-            pl.BlockSpec((bq,), lambda i, t, j: (i,)),
-            pl.BlockSpec((bq,), lambda i, t, j: (i,)),
-            pl.BlockSpec((bq,), lambda i, t, j: (i,)),
-            pl.BlockSpec((1, 4), lambda i, t, j: (jc(j), 0)),
-            pl.BlockSpec((1,), lambda i, t, j: (jc(j),)),
-            pl.BlockSpec((1, cap), lambda i, t, j: (jc(j), 0)),
-            pl.BlockSpec((1, cap), lambda i, t, j: (jc(j), 0)),
-            pl.BlockSpec((1, cap), lambda i, t, j: (jc(j), 0)),
-            pl.BlockSpec((bq, n_probe), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((bq, 1, s, e), lambda i, t, j: (i, jp(j) // npb,
-                                                         0, 0)),
-            pl.BlockSpec((bq, 1, s, e), lambda i, t, j: (i, jp(j) // npb,
-                                                         0, 0)),
-            pl.BlockSpec((bq, bp, s), lambda i, t, j: (i, jp(j), 0)),
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, jp(j))),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, jp(j))),
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, jp(j))),
-            pl.BlockSpec((bq, cap_c), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((bq, cap_c), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((bq, n_probe), lambda i, t, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qp, w), jnp.int32),
-            jax.ShapeDtypeStruct((qp, w), jnp.float32),
-            jax.ShapeDtypeStruct((qp, cap_c), jnp.int32),
-            jax.ShapeDtypeStruct((qp, cap_c), jnp.float32),
-            jax.ShapeDtypeStruct((qp, n_probe), jnp.int8),
-        ],
-        scratch_shapes=[pltpu.VMEM((bq, n_probe), jnp.int32),
-                        pltpu.VMEM((bq, cap_c), jnp.int32),
-                        pltpu.VMEM((bq, cap_c), jnp.int32)],
-        interpret=interpret,
-    )(q0, q1, radius, boxes, cell_reach, cell_c0, cell_c1, slot_reach,
-      slot_idx, lut, table, codes_f, valid_f)
-    counts = counts[:q].reshape(q, n_probe, p_pad)[:, :, :p]
-    dist = dist[:q].reshape(q, n_probe, p_pad)[:, :, :p]
-    cand, cdist = cand[:q], cdist[:q]
-    if p_pad != p:
-        # remap candidate indices from the padded to the real flat layout
-        cand = (cand // p_pad) * p + cand % p_pad
-    return counts, dist, cand, cdist, pok[:q].astype(jnp.bool_)
+    pc0 = jnp.take(cell_c0.reshape(-1), slot_idx)           # (Q, np)
+    pc1 = jnp.take(cell_c1.reshape(-1), slot_idx)
+    preach = jnp.take(slot_reach.reshape(-1), slot_idx)
+    preach = preach.at[:, 0].set(jnp.inf)                   # probe-0 backstop
+    return two_stage(lut, table, codes, valid, cap_c=cap_c, metric=metric,
+                     bq=bq, bp=bp, acc=acc, interpret=interpret,
+                     rt=(q0, q1, radius, pc0, pc1, preach))
 
 
 @functools.partial(jax.jit, static_argnames=("cap_c", "metric", "topc_impl"))

@@ -1,51 +1,47 @@
-"""Pallas TPU kernel: fused two-stage scan — int8 hit-count prefilter and
-survivor-masked ADC in ONE kernel (paper §5.5; DESIGN.md §3).
+"""Pallas TPU kernels: two-stage scan — int8 hit-count prefilter, top-C
+survivor selection and survivor-masked ADC (paper §5.5; DESIGN.md §3).
 
-The paper's core hardware claim is that the RT-core membership test and the
-tensor-core distance accumulation run as a *pipeline*, not two serialized
-passes with a host-visible survivor set in between. The TPU analogue built
-here: a query-batched grid keeps each (query-block, point-block) tile in one
-VMEM residency and runs BOTH stages over it —
+The paper pipelines the RT-core membership test into the tensor-core
+distance stage. The TPU version runs the two stages as two kernels over the
+same (query-block, point-block) tiles, with the survivor selection between
+them:
 
-  phase 0 (grid t=0) — int8 hit scores for the tile plus a streamed
-      per-query top-``cap_c`` (value, flat-index) carried in VMEM scratch:
-      after the last point block, scratch row q holds exactly
-      ``lax.top_k(counts[q], cap_c)`` (ties resolved index-ascending, like
-      ``lax.top_k``), i.e. the stage-1 survivor threshold θ_q = the cap_c-th
-      largest hit count — computed in-kernel, never leaving the chip.
-  phase 1 (grid t=1) — the masked-LUT ADC for the same tiles, but only
-      where ``count >= θ_q``: blocks with zero survivors skip the f32
-      contraction entirely (`pl.when`), surviving lanes are accumulated with
-      the same SLAB one-hot MXU contraction as ``pq_scan``, and the
-      compacted candidate list is folded: each block writes its slice of
-      the (cap_c,) candidate distances.
+  counts kernel — int8 hit scores for every tile (the SLAB one-hot MXU
+      contraction of ``onehot.slab_onehot_dot``); invalid points get
+      ``_NEG``, point-axis padding the lower ``_PAD``.
+  top-C        — ``lax.top_k(counts, cap_c)`` in XLA: ``cand`` is exactly its
+      index choice (value desc, index asc) and θ_q, the survivor floor, is
+      its cap_c-th value. Mosaic has no lowering for ``top_k`` or for the
+      lane gathers a streamed in-kernel merge needs, so the selection sits
+      between the kernels.
+  ADC kernel   — the masked-LUT ADC, only where ``count >= θ_q``: blocks
+      with zero survivors skip the f32 contraction entirely (``pl.when``).
+      ``cand_dist`` is ``dist`` gathered at ``cand``.
 
 Outputs (Q = queries, W = nprobe·P points, C = cap_c):
   counts (Q, np, P) int32 — stage-1 scores (== ``hit_count`` composed)
   dist   (Q, np, P) f32  — ADC totals at survivors, ``bad_value`` elsewhere
   cand   (Q, C)     int32 — flat top-C-by-count indices into (np·P),
-                            bit-identical to ``lax.top_k(counts, C)[1]``
+                            equal to ``lax.top_k(counts, C)[1]``
   cand_dist (Q, C)  f32  — ``dist`` gathered at ``cand``
 
-so the downstream two-stage search needs NO wide top-k and NO second scan:
-stage 2 consumes the compacted candidates directly.
+so the downstream two-stage search needs no wide rerank gather: stage 2
+consumes the compacted candidates directly.
 
-Grid: (Q/bQ, 2, np·Ppad/bP) with bP the largest divisor of P ≤ 128 when
-that divisor is a usable tile (≥ 64), else P is padded per probe to a
-multiple of 128 (a P like 8·prime would otherwise collapse bP to 8 and
-balloon the grid). Padded slots carry a count sentinel STRICTLY below the
-invalid-point `_NEG`, so — with cap_c clamped to the real candidate count —
-they can never enter the top-C, and the real entries' (value desc, index
-asc) selection order is preserved exactly (the padded flat index is
-monotone in the unpadded one); the wrapper remaps candidate indices back
-to the unpadded layout. The query axis pads to bQ and is sliced off.
-VMEM per program ≈
-bQ·S·E·(4+1) [lut+table] + bQ·bP·S [codes] + bQ·bP·SLAB·E·4 [one-hot slab]
-+ 2·bQ·C·4 [top-C scratch] ≈ 2.6 MB at (bQ, bP, S, E, C) = (4, 128, 48,
-256, 400).
+Tiles: grid (Q/bQ, np·Ppad/bP). bP is a multiple of 128 (points run on
+lanes) and P is padded per probe to a multiple of it; padded slots carry
+the ``_PAD`` sentinel STRICTLY below the invalid-point ``_NEG``, so — with
+cap_c clamped to the real candidate count — they never enter the top-C and
+the real entries' (value desc, index asc) order is unchanged (the padded
+flat index is monotone in the unpadded one); the wrapper maps candidate
+indices back to the unpadded layout. bQ is the whole query axis up to 8
+queries, else a multiple of 8 with the query axis padded and sliced off.
+The per-query LUT/hit table is one (bQ, S, E) block per probe. VMEM per
+program ≈ bQ·S·E·4 [LUT] + bQ·bP·S [codes] + bP·SLAB·E·4 [one-hot slab]
+≈ 1.5 MB at (bQ, bP, S, E) = (8, 128, 48, 256).
 
 ``fused_two_stage_host`` is the schedule-equivalent host path used for
-off-TPU serving (see its docstring); the Pallas kernel itself is validated
+off-TPU serving (see its docstring); the kernels themselves are validated
 in interpret mode by tests/test_fused_kernel.py.
 """
 from __future__ import annotations
@@ -55,41 +51,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .ops import slab_onehot_dot
+from .onehot import lane_tile, slab_onehot_dot, sublane_tile
 
-DEFAULT_BQ = 4     # query rows per program
-DEFAULT_BP = 128   # points per program (upper bound; must divide P)
+DEFAULT_BQ = 8     # query rows per program
+DEFAULT_BP = 128   # points per program
 SLAB = 8           # subspaces one-hot-expanded at a time (VMEM control)
 
 _NEG = -(2 ** 30)  # invalid-point count sentinel (matches hit_count kernel)
 # point-padding sentinel: STRICTLY below _NEG so a padded slot loses every
 # tie against a real (even invalid) point and never enters the top-C
 _PAD = -(2 ** 30) - 1
-# scratch init must sit STRICTLY below every real/pad count or top_k's
-# position-asc tie-break would keep a stale scratch slot in place of a
-# genuine sentinel-count point
-_INIT = -(2 ** 31)
 
 # hit-count accumulation dtypes the autotuner may pick (kernels.autotune).
-# Every option is EXACT for hit counts (|count| <= S <= 48 << 2^8): "f32"
-# is the historical default, "bf16" halves the one-hot operand bytes (8
-# mantissa bits hold integers to 256), "int8" keeps the table in int8 and
-# accumulates on the integer pipeline (int32) — so the knob is
-# result-invariant by construction (tests/test_autotune.py pins this).
+# Every option is EXACT for hit counts (|count| <= S << 2^8 terms of
+# {-1, 0, 1}): "f32" contracts f32 operands, "bf16" halves the one-hot
+# operand bytes (8 mantissa bits hold these integers), "int8" keeps the
+# table in int8 and accumulates on the integer pipeline (int32) — so the
+# knob is result-invariant by construction (tests/test_autotune.py).
 ACC_DTYPES = ("f32", "bf16", "int8")
 
 
 def count_dot(codes, table_i8, *, n_entries, acc="f32", slab=SLAB):
-    """Stage-1 hit-count contraction with a tunable accumulation dtype.
+    """Stage-1 hit-count contraction with a tunable operand dtype.
 
-    codes (..., bP, S) int32, table_i8 (..., S, E) int8 → (..., bP) int32.
-    ``acc`` selects the MXU operand/accumulation dtype (see ACC_DTYPES);
-    all options produce bit-identical int32 counts.
+    codes (bP, S) int32, table_i8 (S, E) int8 → (1, bP) int32.
+    ``acc`` selects the MXU operand dtype (see ACC_DTYPES); all options
+    produce bit-identical int32 counts.
     """
     if acc == "bf16":
-        tab, od = table_i8.astype(jnp.bfloat16), jnp.bfloat16
+        tab, od = table_i8.astype(jnp.bfloat16), jnp.float32
     elif acc == "int8":
         tab, od = table_i8, jnp.int32
     else:
@@ -99,81 +90,149 @@ def count_dot(codes, table_i8, *, n_entries, acc="f32", slab=SLAB):
     return out.astype(jnp.int32)
 
 
-def _fused_kernel(lut_ref, table_ref, codes_ref, valid_ref,
-                  counts_ref, dist_ref, cand_ref, cdist_ref,
-                  topv_ref, topi_ref, *, n_entries, cap_c, bp, p_real,
-                  p_pad, bad_value, acc):
-    t = pl.program_id(1)           # 0 = hit-count pass, 1 = masked-ADC pass
-    j = pl.program_id(2)           # flat point-block index over np·Ppad
-    codes = codes_ref[...].astype(jnp.int32)          # (bQ, bP, S)
-    valid = valid_ref[...]                            # (bQ, bP)
-    bq = codes.shape[0]
+def _stack_rows(bq, bp, dtype, row):
+    """(bQ, bP) array whose row q is ``row(q)`` (1, bP), for q < bQ.
 
-    # stage 1 (both phases — phase 1 re-derives the survivor mask from it):
-    # batched SLAB one-hot contraction; accumulation of {-1,0,1} terms is
-    # exact in every ACC_DTYPES option (|count| <= S << 2^8), so counts are
-    # bit-identical to the int32-path hit_count kernel regardless of ``acc``.
-    cnt = count_dot(codes, table_ref[...][:, 0], n_entries=n_entries,
-                    acc=acc)
-    bad_count = _NEG
-    if p_pad != p_real:            # point axis padded: mark pad slots so
-        lane = j * bp + jax.lax.broadcasted_iota(jnp.int32, (bq, bp), 1)
-        bad_count = jnp.where(lane % p_pad < p_real, _NEG, _PAD)
-    counts = jnp.where(valid, cnt, bad_count)
-    counts_ref[...] = counts
-
-    @pl.when(t == 0)
-    def _stage1():
-        @pl.when(j == 0)
-        def _init():
-            topv_ref[...] = jnp.full_like(topv_ref, _INIT)
-            topi_ref[...] = jnp.zeros_like(topi_ref)
-        # streamed top-C merge: previously selected entries sit at the lower
-        # concat positions, so lax.top_k's position-ascending tie-break
-        # reproduces the global (value desc, index asc) order exactly.
-        newi = j * bp + jax.lax.broadcasted_iota(jnp.int32, (bq, bp), 1)
-        runv = jnp.concatenate([topv_ref[...], counts], axis=1)
-        runi = jnp.concatenate([topi_ref[...], newi], axis=1)
-        v, pos = jax.lax.top_k(runv, cap_c)
-        topv_ref[...] = v
-        topi_ref[...] = jnp.take_along_axis(runi, pos, axis=1)
-        cand_ref[...] = topi_ref[...]
-        cdist_ref[...] = jnp.full_like(cdist_ref, bad_value)
-        dist_ref[...] = jnp.full((bq, codes.shape[1]), bad_value, jnp.float32)
-
-    @pl.when(t == 1)
-    def _stage2():
-        theta = topv_ref[...][:, cap_c - 1]           # (bQ,) survivor floor
-        keep = valid & (counts >= theta[:, None])
-        cand_ref[...] = topi_ref[...]
-        any_keep = jnp.any(keep)
-
-        @pl.when(any_keep)
-        def _adc():
-            lut = lut_ref[...][:, 0]                  # (bQ, S, E) f32
-            acc = slab_onehot_dot(codes, lut, n_entries=n_entries,
-                                  out_dtype=jnp.float32, slab=SLAB)
-            dist = jnp.where(keep, acc, bad_value)
-            dist_ref[...] = dist
-            # compaction fold: this block's slice of the candidate list
-            local = topi_ref[...] - j * bp            # (bQ, C)
-            inblk = (local >= 0) & (local < bp)
-            got = jnp.take_along_axis(dist, jnp.clip(local, 0, bp - 1),
-                                      axis=1)
-            cdist_ref[...] = jnp.where(inblk, got, cdist_ref[...])
-
-        # zero-survivor block: stage-2 f32 work skipped entirely
-        @pl.when(jnp.logical_not(any_keep))
-        def _skip():
-            dist_ref[...] = jnp.full((bq, codes.shape[1]), bad_value,
-                                     jnp.float32)
+    A ``fori_loop`` rather than an unrolled Python loop, so one query's
+    one-hot temporaries are dead before the next query's are built (the
+    unrolled form exceeds the 16 MB scoped VMEM at S = 100)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bp), 0)
+    return jax.lax.fori_loop(
+        0, bq, lambda q, acc: jnp.where(rows == q, row(q), acc),
+        jnp.zeros((bq, bp), dtype))
 
 
-def _largest_divisor(n: int, cap: int) -> int:
-    for d in range(min(cap, n), 0, -1):
-        if n % d == 0:
-            return d
-    return 1
+def _count_kernel(*refs, n_entries, bq, bp, p_real, p_pad, acc, rt):
+    """Stage 1 over one (bQ, bP) tile: hit counts, sentinel-masked.
+
+    With ``rt`` the tile's probe first takes the RT sphere test (the
+    three-stage kernel's stage 0): the query disc (q0, q1, radius) against
+    the probed cluster's projected centroid and reach, as (bQ, 1) columns.
+    Failing probes mask ``valid``; the verdict is also written out.
+    """
+    if rt:
+        (q0_ref, q1_ref, r_ref, c0_ref, c1_ref, reach_ref, table_ref,
+         codes_ref, valid_ref, counts_ref, pok_ref) = refs
+    else:
+        table_ref, codes_ref, valid_ref, counts_ref = refs
+    j = pl.program_id(1)                              # flat point block
+    valid = valid_ref[...] != 0                       # (bQ, bP)
+    if rt:
+        sx = q0_ref[...] - c0_ref[...]                # (bQ, 1)
+        sy = q1_ref[...] - c1_ref[...]
+        d2 = sx * sx + sy * sy
+        thr = r_ref[...] + reach_ref[...]
+        hit = (thr >= 0.0) & (d2 <= thr * thr)
+        pok_ref[...] = hit.astype(jnp.int32)
+        valid = valid & hit
+    bad = _NEG
+    if p_pad != p_real:            # point axis padded: mark pad slots
+        lane = j * bp + jax.lax.broadcasted_iota(jnp.int32, (1, bp), 1)
+        bad = jnp.where(lane % p_pad < p_real, _NEG, _PAD)
+    cnt = _stack_rows(bq, bp, jnp.int32, lambda q: count_dot(
+        codes_ref[q].astype(jnp.int32), table_ref[q], n_entries=n_entries,
+        acc=acc))
+    counts_ref[...] = jnp.where(valid, cnt, bad)
+
+
+def _adc_kernel(theta_ref, lut_ref, codes_ref, counts_ref, dist_ref, *,
+                n_entries, bq, bad_value):
+    """Stage 2 over one tile: masked-LUT ADC where ``count >= θ``.
+
+    Real points are exactly those with ``count > _NEG`` (valid counts lie
+    in [-S, S]), so the survivor mask needs no ``valid`` input."""
+    counts = counts_ref[...]                          # (bQ, bP)
+    keep = (counts > _NEG) & (counts >= theta_ref[...])
+    dist_ref[...] = jnp.full(dist_ref.shape, bad_value, jnp.float32)
+
+    # zero-survivor block: stage-2 f32 work skipped entirely
+    @pl.when(jnp.max(keep.astype(jnp.int32)) > 0)
+    def _adc():
+        adc = _stack_rows(bq, keep.shape[1], jnp.float32,
+                          lambda q: slab_onehot_dot(
+                              codes_ref[q].astype(jnp.int32), lut_ref[q],
+                              n_entries=n_entries, out_dtype=jnp.float32,
+                              slab=SLAB))
+        dist_ref[...] = jnp.where(keep, adc, bad_value)
+
+
+def two_stage(lut, table, codes, valid, *, cap_c, metric, bq, bp, acc,
+              interpret, rt=None):
+    """Shared body of the two- and three-stage scans (see module docstring).
+
+    ``rt`` is None, or ``(q0, q1, radius, pc0, pc1, preach)``: the (Q,)
+    ray-plane queries and the (Q, np) projected centroid and reach of each
+    probed cluster. Then the counts kernel applies the sphere test per
+    probe and a fifth output, ``probe_ok`` (Q, np) bool, is returned.
+    """
+    q, n_probe, p, s = codes.shape
+    e = lut.shape[-1]
+    cap_c = max(1, min(cap_c, n_probe * p))
+    bp = lane_tile(bp or DEFAULT_BP)
+    p_pad = -(-p // bp) * bp
+    bq = sublane_tile(q, bq)
+    pad_q = (-q) % bq
+    qp = q + pad_q
+    pads = ((0, pad_q), (0, 0), (0, p_pad - p))
+    codes = jnp.pad(codes, pads + ((0, 0),))
+    valid = jnp.pad(valid, pads)
+    if pad_q:                         # (Q, np, S, E) tables: copy only if due
+        lut = jnp.pad(lut, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
+        table = jnp.pad(table, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
+    w = n_probe * p_pad
+    npb = p_pad // bp                 # point blocks per probe
+    grid = (qp // bq, w // bp)
+    tab_spec = pl.BlockSpec((bq, None, s, e), lambda i, j: (i, j // npb, 0, 0))
+    codes_spec = pl.BlockSpec((bq, bp, s), lambda i, j: (i, j, 0))
+    row_spec = pl.BlockSpec((bq, bp), lambda i, j: (i, j))
+    col_spec = pl.BlockSpec((bq, 1), lambda i, j: (i, 0))
+    in_specs = [tab_spec, codes_spec, row_spec]
+    args = [table, codes.reshape(qp, w, s),
+            valid.reshape(qp, w).astype(jnp.int32)]
+    out_specs = [row_spec]
+    out_shape = [jax.ShapeDtypeStruct((qp, w), jnp.int32)]
+    if rt is not None:
+        # per-probe operands as (np, Q, 1): a tile's probe is j // npb
+        probe_spec = pl.BlockSpec((None, bq, 1),
+                                  lambda i, j: (j // npb, i, 0))
+        cols = [jnp.pad(x, (0, pad_q))[:, None] for x in rt[:3]]
+        probes = [jnp.pad(x, ((0, pad_q), (0, 0))).T[..., None]
+                  for x in rt[3:]]
+        in_specs = [col_spec] * 3 + [probe_spec] * 3 + in_specs
+        args = cols + probes + args
+        out_specs.append(probe_spec)
+        out_shape.append(jax.ShapeDtypeStruct((n_probe, qp, 1), jnp.int32))
+    outs = pl.pallas_call(
+        functools.partial(_count_kernel, n_entries=e, bq=bq, bp=bp,
+                          p_real=p, p_pad=p_pad, acc=acc, rt=rt is not None),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret,
+    )(*args)
+    counts = outs[0]
+
+    topv, cand = jax.lax.top_k(counts, cap_c)         # (Qp, C)
+    theta = topv[:, cap_c - 1:]                       # (Qp, 1) survivor floor
+    bad = float("inf") if metric == "l2" else float("-inf")
+    dist = pl.pallas_call(
+        functools.partial(_adc_kernel, n_entries=e, bq=bq, bad_value=bad),
+        grid=grid, in_specs=[col_spec, tab_spec, codes_spec, row_spec],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((qp, w), jnp.float32),
+        interpret=interpret,
+    )(theta, lut, args[-2], counts)
+    cdist = jnp.take_along_axis(dist, cand, axis=1)[:q]
+    cand = cand[:q]
+    counts = counts[:q].reshape(q, n_probe, p_pad)[:, :, :p]
+    dist = dist[:q].reshape(q, n_probe, p_pad)[:, :, :p]
+    if p_pad != p:
+        # remap candidate indices from the padded to the real flat layout
+        # (cand never contains pad slots — see _PAD — and the mapping is
+        # monotone, so top-k order is preserved)
+        cand = (cand // p_pad) * p + cand % p_pad
+    out = (counts, dist, cand, cdist)
+    if rt is not None:
+        out += (outs[1][:, :q, 0].T > 0,)
+    return out
 
 
 @functools.partial(jax.jit,
@@ -188,71 +247,10 @@ def fused_two_stage(lut: jnp.ndarray, table: jnp.ndarray, codes: jnp.ndarray,
     (counts (Q, np, P) i32, dist (Q, np, P) f32,
      cand (Q, C) i32, cand_dist (Q, C) f32). See module docstring.
     ``bq``/``bp``/``acc`` are the autotuner's tile/accumulation knobs
-    (``kernels.autotune``) — all result-invariant."""
-    q, n_probe, p, s = codes.shape
-    e = lut.shape[-1]
-    cap_c = max(1, min(cap_c, n_probe * p))
-    bp = _largest_divisor(p, bp or DEFAULT_BP)
-    if bp < min(64, p):
-        # divisor cliff (e.g. P = 8·prime would give bp = 8): pad the point
-        # axis per probe to a full tile instead; pad slots are masked in the
-        # kernel with the below-_NEG _PAD sentinel, so candidate selection
-        # over the REAL entries is unchanged (cap_c <= np·P real entries
-        # always outrank every pad slot)
-        bp = DEFAULT_BP
-        pad_p = (-p) % bp
-        codes = jnp.pad(codes, ((0, 0), (0, 0), (0, pad_p), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, 0), (0, pad_p)))
-    p_pad = codes.shape[2]
-    w = n_probe * p_pad
-    bq = min(bq, q)
-    pad_q = (-q) % bq
-    if pad_q:
-        lut = jnp.pad(lut, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        table = jnp.pad(table, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        codes = jnp.pad(codes, ((0, pad_q), (0, 0), (0, 0), (0, 0)))
-        valid = jnp.pad(valid, ((0, pad_q), (0, 0), (0, 0)))
-    qp = q + pad_q
-    codes_f = codes.reshape(qp, w, s)
-    valid_f = valid.reshape(qp, w)
-    npb = p_pad // bp                 # point blocks per probe
-    bad = float("inf") if metric == "l2" else float("-inf")
-
-    counts, dist, cand, cdist = pl.pallas_call(
-        functools.partial(_fused_kernel, n_entries=e, cap_c=cap_c, bp=bp,
-                          p_real=p, p_pad=p_pad, bad_value=bad, acc=acc),
-        grid=(qp // bq, 2, n_probe * npb),
-        in_specs=[
-            pl.BlockSpec((bq, 1, s, e), lambda i, t, j: (i, j // npb, 0, 0)),
-            pl.BlockSpec((bq, 1, s, e), lambda i, t, j: (i, j // npb, 0, 0)),
-            pl.BlockSpec((bq, bp, s), lambda i, t, j: (i, j, 0)),
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, j)),
-            pl.BlockSpec((bq, bp), lambda i, t, j: (i, j)),
-            pl.BlockSpec((bq, cap_c), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((bq, cap_c), lambda i, t, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qp, w), jnp.int32),
-            jax.ShapeDtypeStruct((qp, w), jnp.float32),
-            jax.ShapeDtypeStruct((qp, cap_c), jnp.int32),
-            jax.ShapeDtypeStruct((qp, cap_c), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bq, cap_c), jnp.int32),
-                        pltpu.VMEM((bq, cap_c), jnp.int32)],
-        interpret=interpret,
-    )(lut, table, codes_f, valid_f)
-    counts = counts[:q].reshape(q, n_probe, p_pad)[:, :, :p]
-    dist = dist[:q].reshape(q, n_probe, p_pad)[:, :, :p]
-    cand, cdist = cand[:q], cdist[:q]
-    if p_pad != p:
-        # remap candidate indices from the padded to the real flat layout
-        # (cand never contains pad slots — see _PAD — and the mapping is
-        # monotone, so top-k order is preserved)
-        cand = (cand // p_pad) * p + cand % p_pad
-    return counts, dist, cand, cdist
+    (``kernels.autotune``) — all result-invariant; tiles are rounded to
+    ones the chip accepts."""
+    return two_stage(lut, table, codes, valid, cap_c=cap_c, metric=metric,
+                     bq=bq, bp=bp, acc=acc, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("cap_c", "metric", "topc_impl"))

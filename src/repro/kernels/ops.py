@@ -1,11 +1,13 @@
-"""Jit'd public wrappers over the Pallas kernels.
+"""Jit'd public wrappers over the Pallas kernels, chosen by platform.
 
-On CPU (this container) kernels execute with ``interpret=True`` — the kernel
-body runs in Python over real blocks, validating BlockSpec tiling and
-semantics. On TPU they compile natively. ``use_pallas()`` picks the backend.
+On a TPU every wrapper runs its compiled Pallas kernel. Off-TPU the plain
+scans (``selective_lut``, ``hit_count``, ``pq_scan``) run the same kernels
+in interpret mode — the kernel body executes in Python over real blocks,
+validating BlockSpec tiling and semantics — while the fused scans and the
+RT walk dispatch to schedule-equivalent host paths. ``_on_tpu()`` decides.
 
-Also home of :func:`slab_onehot_dot`, the SLAB-wise one-hot ``dot_general``
-shared by the kernel bodies (hit_count / pq_scan / fused_two_stage).
+:func:`slab_onehot_dot`, the one-hot MXU contraction the kernel bodies
+share, lives in ``kernels.onehot`` and is re-exported here.
 """
 from __future__ import annotations
 
@@ -14,62 +16,33 @@ import functools
 import jax
 import jax.numpy as jnp
 
-DEFAULT_SLAB = 8
+from repro.rt import intersect as _rt
 
-
-def slab_onehot_dot(codes: jnp.ndarray, tab: jnp.ndarray, *, n_entries: int,
-                    out_dtype=jnp.float32,
-                    slab: int = DEFAULT_SLAB) -> jnp.ndarray:
-    """``out[..., p] = sum_s tab[..., s, codes[..., p, s]]`` on the MXU.
-
-    codes (..., bP, S) int, tab (..., S, E) → (..., bP) in ``out_dtype``.
-
-    The per-(point, subspace) LUT gather is expressed as a one-hot
-    contraction — ``one_hot(codes_slab) (..., bP, sl·E) · tab_slab
-    (..., sl·E, 1)`` — the TPU analogue of the paper's Tensor-core
-    "A × B(=ones)" accumulation trick (§5.3): quantized codes choose MXU
-    operand rows instead of driving scalar lookups. The one-hot is formed
-    ``slab`` subspaces at a time to bound VMEM (≈ prod(lead)·bP·slab·E·
-    itemsize per slab). Accumulation dtype is pinned by ``out_dtype`` via
-    ``preferred_element_type``: int32 for the int8 hit path, f32 for the ADC
-    path (tests/test_kernels.py pins both).
-
-    Shared by the kernel bodies of ``hit_count`` (int32), ``pq_scan`` (f32)
-    and ``fused_two_stage`` (f32, batched) — callable both inside Pallas
-    kernels and as plain jnp.
-    """
-    n_sub = codes.shape[-1]
-    *lead, bp, _ = codes.shape
-    nb = len(lead)
-    dnums = (((nb + 1,), (nb,)), (tuple(range(nb)), tuple(range(nb))))
-    acc = jnp.zeros((*lead, bp), out_dtype)
-    for s0 in range(0, n_sub, slab):
-        sl = min(slab, n_sub - s0)
-        oh = jax.nn.one_hot(codes[..., s0:s0 + sl], n_entries,
-                            dtype=out_dtype)          # (..., bP, sl, E)
-        acc = acc + jax.lax.dot_general(
-            oh.reshape(*lead, bp, sl * n_entries),
-            tab[..., s0:s0 + sl, :].reshape(*lead, sl * n_entries, 1),
-            dnums, preferred_element_type=out_dtype)[..., 0]
-    return acc
-
-
-# NOTE: these imports sit BELOW slab_onehot_dot on purpose — the kernel
-# modules import it from here at module load, so it must already be bound
-# when a kernel module (imported by this block) re-enters the partially
-# initialised ``ops``.
-from . import fused_three_stage as _fused3  # noqa: E402
-from . import fused_two_stage as _fused  # noqa: E402
-from . import hit_count as _hit  # noqa: E402
-from . import ivf_filter as _filt  # noqa: E402
-from . import pq_scan as _scan  # noqa: E402
-from . import selective_lut as _lut  # noqa: E402
-from repro.rt import intersect as _rt  # noqa: E402
+from . import fused_three_stage as _fused3
+from . import fused_two_stage as _fused
+from . import hit_count as _hit
+from . import ivf_filter as _filt
+from . import pq_scan as _scan
+from . import selective_lut as _lut
+from .onehot import slab_onehot_dot  # noqa: F401  (public re-export)
 
 
 @functools.cache
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def default_impl() -> str:
+    """The search path a caller gets when it names none: ``"pallas"`` (the
+    compiled kernels) on a TPU, ``"ref"`` (the jnp reference) elsewhere,
+    where the kernels would only run in the interpreter."""
+    return "pallas" if _on_tpu() else "ref"
+
+
+def _kernels(impl: str | None) -> bool:
+    """Whether a dispatcher runs its compiled kernel: on a TPU, unless the
+    caller asked for the jnp reference (``impl="ref"``)."""
+    return impl != "ref" and _on_tpu()
 
 
 def _interpret() -> bool:
@@ -89,24 +62,12 @@ def build_selective_lut(qsub: jnp.ndarray, entries: jnp.ndarray,
         b *= d
     q0 = qsub[..., 0].reshape(b, s)
     q1 = qsub[..., 1].reshape(b, s)
-    # pad batch to the block size
-    bb = _lut.DEFAULT_BB
-    pad_b = (-b) % bb
-    if pad_b:
-        q0 = jnp.pad(q0, ((0, pad_b), (0, 0)))
-        q1 = jnp.pad(q1, ((0, pad_b), (0, 0)))
-    tau2 = tau.reshape(b, s)
-    if pad_b:
-        tau2 = jnp.pad(tau2, ((0, pad_b), (0, 0)))
-    bs = _lut.DEFAULT_BS
-    while s % bs:
-        bs //= 2
     lut, hit = _lut.selective_lut(q0, q1, entries[..., 0], entries[..., 1],
-                                  entry_sq, tau2, metric=metric, bs=bs,
+                                  entry_sq, tau.reshape(b, s), metric=metric,
                                   interpret=_interpret())
     e = entries.shape[1]
-    lut = lut[:b].reshape(*lead, s, e)
-    hit = hit[:b].reshape(*lead, s, e)
+    lut = lut.reshape(*lead, s, e)
+    hit = hit.reshape(*lead, s, e)
     if metric == "ip":
         # The kernel substitutes pruned entries with -tau^2/2 (the exact
         # floor needs a row reduction over kept entries, which would cost a
@@ -146,23 +107,25 @@ def hit_count_scan(table: jnp.ndarray, codes: jnp.ndarray,
 
 def fused_two_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
                          codes: jnp.ndarray, valid: jnp.ndarray, *,
-                         cap_c: int, metric: str = "l2"):
-    """Fused two-stage scan: hit-count prefilter → in-kernel survivor
-    threshold → masked ADC + top-candidate compaction, in one pass.
+                         cap_c: int, metric: str = "l2",
+                         impl: str | None = None):
+    """Fused two-stage scan: hit-count prefilter → survivor threshold →
+    masked ADC + top-candidate compaction.
 
     mlut/table (Q, np, S, E), codes (Q, np, P, S), valid (Q, np, P) →
     (counts (Q, np, P) i32, dist (Q, np, P) f32, cand (Q, C) i32,
      cand_dist (Q, C) f32); ``cand`` is the top-cap_c-by-count candidate
     set, ``cand_dist`` their masked-LUT totals — the two-stage search
-    consumes these directly, with no wide top-k and no second scan.
+    consumes these directly, with no separate rerank gather.
 
-    On TPU this is the fused Pallas kernel (one VMEM residency per tile,
-    RT→TC-pipeline analogue). Off-TPU it dispatches to the
-    schedule-equivalent host path rather than interpret mode: a 2-trip
-    grid under the interpreter would serialize the serving hot path, and
-    the host path's histogram selection is the same survivor-threshold
-    idea expressed CPU-natively. The interpret-mode kernel is validated
-    against the composed kernels by tests/test_fused_kernel.py.
+    On TPU these are the two-stage Pallas kernels (counts, ``lax.top_k``,
+    survivor-masked ADC), unless ``impl="ref"`` asks for the jnp
+    reference. Otherwise it dispatches to the schedule-equivalent
+    host path rather than interpret mode: interpreting the kernels would
+    serialize the serving hot path, and the host path's histogram
+    selection is the same survivor-threshold idea expressed CPU-natively.
+    The interpret-mode kernels are validated against the composed kernels
+    by tests/test_fused_kernel.py.
 
     The result-invariant tile/θ-selection knobs come from the process
     active :class:`repro.kernels.autotune.KernelConfig` (read at trace
@@ -170,7 +133,7 @@ def fused_two_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
     """
     from . import autotune
     cfg = autotune.active_config("fused_two_stage")
-    if _on_tpu():
+    if _kernels(impl):
         return _fused.fused_two_stage(mlut, table, codes, valid,
                                       cap_c=cap_c, metric=metric,
                                       bq=cfg.bq, bp=cfg.bp,
@@ -183,18 +146,17 @@ def fused_two_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
 def fused_three_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
                            codes: jnp.ndarray, valid: jnp.ndarray,
                            q0: jnp.ndarray, q1: jnp.ndarray,
-                           radius: jnp.ndarray, boxes: jnp.ndarray,
-                           cell_reach: jnp.ndarray, cell_c0: jnp.ndarray,
+                           radius: jnp.ndarray, cell_c0: jnp.ndarray,
                            cell_c1: jnp.ndarray, slot_reach: jnp.ndarray,
                            slot_idx: jnp.ndarray, *, cap_c: int,
-                           metric: str = "l2"):
+                           metric: str = "l2", impl: str | None = None):
     """Single-residency three-stage scan: RT sphere test → hit-count
     prefilter → masked ADC + top-candidate compaction, in one pass.
 
     The :func:`fused_two_stage_scan` contract with the RT probe filter
     folded in as stage 0: ``q0``/``q1``/``radius`` are the ray-plane
-    queries, ``boxes``/``cell_reach``/``cell_c0``/``cell_c1``/
-    ``slot_reach`` the ``CentroidGrid`` layout, and ``slot_idx`` (Q, np)
+    queries, ``cell_c0``/``cell_c1``/``slot_reach`` the ``CentroidGrid``
+    layout, and ``slot_idx`` (Q, np)
     int32 the probed clusters' flat slot indices
     (``grid.slot_of[cids]``). Returns the two-stage 4-tuple plus
     ``probe_ok`` (Q, np) bool — identical to the host-side
@@ -211,10 +173,10 @@ def fused_three_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
     """
     from . import autotune
     cfg = autotune.active_config("fused_three_stage")
-    if _on_tpu():
+    if _kernels(impl):
         return _fused3.fused_three_stage(
-            mlut, table, codes, valid, q0, q1, radius, boxes, cell_reach,
-            cell_c0, cell_c1, slot_reach, slot_idx, cap_c=cap_c,
+            mlut, table, codes, valid, q0, q1, radius, cell_c0, cell_c1,
+            slot_reach, slot_idx, cap_c=cap_c,
             metric=metric, bq=cfg.bq, bp=cfg.bp, acc=cfg.acc_dtype)
     return _fused3.fused_three_stage_host(
         mlut, table, codes, valid, q0, q1, radius, cell_c0, cell_c1,
@@ -225,7 +187,8 @@ def fused_three_stage_scan(mlut: jnp.ndarray, table: jnp.ndarray,
 def rt_sphere_hits(q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
                    boxes: jnp.ndarray, cell_reach: jnp.ndarray,
                    c0: jnp.ndarray, c1: jnp.ndarray,
-                   slot_reach: jnp.ndarray) -> jnp.ndarray:
+                   slot_reach: jnp.ndarray, *,
+                   impl: str | None = None) -> jnp.ndarray:
     """RT-core-style sphere-intersection filter (stage-1 spatial pruning).
 
     Parameters
@@ -239,6 +202,8 @@ def rt_sphere_hits(q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
     c0, c1, slot_reach : jnp.ndarray
         (n_cells, cap) f32 projected centroid planes and per-slot reaches
         (``-inf`` = pad slot).
+    impl : str, optional
+        ``"ref"`` forces the dense jnp path on a TPU too.
 
     Returns
     -------
@@ -254,7 +219,7 @@ def rt_sphere_hits(q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
     :func:`fused_two_stage_scan`; results are identical either way
     because the cell skip is conservative.
     """
-    if _on_tpu():
+    if _kernels(impl):
         return _rt.sphere_hits(q0, q1, radius, boxes, cell_reach,
                                c0, c1, slot_reach)
     return _rt.sphere_hits_host(q0, q1, radius, c0, c1, slot_reach)

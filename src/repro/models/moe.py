@@ -56,7 +56,6 @@ def _moe_ffn_ep(x: jnp.ndarray, p: dict, moe: MoEConfig) -> jnp.ndarray:
     """Expert-parallel shard_map: tokens replicated over "model", each model
     shard dispatches ONLY to its E/16 local experts and contributes a
     partial combine; one psum over "model" finishes the layer."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import sharding as shmod
 
@@ -104,12 +103,12 @@ def _moe_ffn_ep(x: jnp.ndarray, p: dict, moe: MoEConfig) -> jnp.ndarray:
         y = jax.lax.psum(y, "model")
         return y.reshape(xl.shape)
 
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=shmod.mesh(),
         in_specs=(P(batch, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=P(batch, None, None), check_rep=False)(
+        out_specs=P(batch, None, None), check_vma=False)(
         x, p["router"], p["w_gate"], p["w_in"], p["w_out"])
 
     if moe.n_shared:

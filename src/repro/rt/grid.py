@@ -117,6 +117,18 @@ def _projection(dim: int, seed: int) -> np.ndarray:
     return q.astype(np.float32)
 
 
+def _decoded_residuals(data, rows: int = 8192) -> np.ndarray:
+    """(N, D) host array of every point's PQ-reconstructed residual,
+    decoded ``rows`` points at a time: decoding all N at once materializes
+    an (N·S, 2) array, which a TPU pads to 128 lanes (30 GB at N = 600K,
+    S = 100)."""
+    from repro.core.pq import decode
+    codes = data.codes
+    return np.concatenate([
+        np.asarray(decode(codes[i:i + rows], data.codebook))
+        for i in range(0, codes.shape[0], rows)])
+
+
 def _radius_calibration(data, proj: np.ndarray, reach: np.ndarray, *,
                         metric: str, coverage: float, n_queries: int,
                         k: int = 10, seed: int = 0,
@@ -135,7 +147,6 @@ def _radius_calibration(data, proj: np.ndarray, reach: np.ndarray, *,
     true-neighbour clusters as survivors.
     """
     from repro.core import density as density_lib
-    from repro.core.pq import decode
     from repro.core.ref import exact_topk
 
     cent = np.asarray(data.ivf.centroids, np.float32)
@@ -143,7 +154,7 @@ def _radius_calibration(data, proj: np.ndarray, reach: np.ndarray, *,
     if points is not None:
         pts = np.asarray(points, np.float32)
     else:
-        pts = cent[labels] + np.asarray(decode(data.codes, data.codebook))
+        pts = cent[labels] + _decoded_residuals(data)
     n = pts.shape[0]
     nq = min(n_queries, n)
     rng = np.random.default_rng(seed)
@@ -217,8 +228,7 @@ def build_grid(data, *, metric: str = "l2", grid_size: int | None = None,
     if points is not None:
         res = np.asarray(points, np.float32) - cent[labels]
     else:
-        from repro.core.pq import decode
-        res = np.asarray(decode(data.codes, data.codebook))
+        res = _decoded_residuals(data)
     rp = res @ proj                                            # (N, 2)
     rnorm = np.sqrt(np.sum(rp * rp, axis=-1))
     reach = np.zeros((c,), np.float32)
@@ -309,7 +319,8 @@ def query_radius(grid: CentroidGrid, tau: jnp.ndarray,
 
 
 def survivor_mask(grid: CentroidGrid, queries: jnp.ndarray,
-                  radius: jnp.ndarray) -> jnp.ndarray:
+                  radius: jnp.ndarray, *, impl: str | None = None
+                  ) -> jnp.ndarray:
     """Per-(query, cluster) sphere-intersection hits, in cluster order.
 
     Projects the queries onto the ray plane, runs the cell-walk
@@ -324,6 +335,8 @@ def survivor_mask(grid: CentroidGrid, queries: jnp.ndarray,
         (Q, D) f32 full-space queries.
     radius : jnp.ndarray
         (Q,) f32 ray-plane radii (:func:`query_radius`).
+    impl : str, optional
+        Forwarded to ``rt_sphere_hits`` (``"ref"``: dense jnp path).
 
     Returns
     -------
@@ -336,7 +349,7 @@ def survivor_mask(grid: CentroidGrid, queries: jnp.ndarray,
     qp = queries.astype(jnp.float32) @ grid.proj
     hits = kops.rt_sphere_hits(qp[:, 0], qp[:, 1], radius, grid.boxes,
                                grid.cell_reach, grid.cell_c0, grid.cell_c1,
-                               grid.slot_reach)
+                               grid.slot_reach, impl=impl)
     return jnp.take(hits, grid.slot_of, axis=1)
 
 

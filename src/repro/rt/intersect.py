@@ -34,34 +34,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.onehot import sublane_tile
+
 DEFAULT_BQ = 8   # query rows per program
 
 
 def _sphere_kernel(q0_ref, q1_ref, r_ref, box_ref, creach_ref,
                    c0_ref, c1_ref, reach_ref, out_ref):
-    """One (query-block, cell) program: AABB pre-test, then disc tests."""
-    q0 = q0_ref[...]                                  # (bQ,)
+    """One (query-block, cell) program: AABB pre-test, then disc tests.
+
+    Queries arrive as (bQ, 1) columns and the cell's data as (1, ·) rows,
+    so every test is a lane/sublane broadcast."""
+    q0 = q0_ref[...]                                  # (bQ, 1)
     q1 = q1_ref[...]
     r = r_ref[...]
     box = box_ref[...]                                # (1, 4) lo0 lo1 hi0 hi1
-    dx = jnp.clip(q0, box[0, 0], box[0, 2]) - q0      # query → AABB offset
-    dy = jnp.clip(q1, box[0, 1], box[0, 3]) - q1
+    dx = jnp.clip(q0, box[:, 0:1], box[:, 2:3]) - q0  # query → AABB offset
+    dy = jnp.clip(q1, box[:, 1:2], box[:, 3:4]) - q1
     d2_cell = dx * dx + dy * dy
-    thr_cell = r + creach_ref[...][0]
+    thr_cell = r + creach_ref[...]                    # (bQ, 1)
     live = (thr_cell >= 0.0) & (d2_cell <= thr_cell * thr_cell)
     out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    @pl.when(jnp.any(live))
+    @pl.when(jnp.max(live.astype(jnp.int32)) > 0)
     def _slot_tests():
-        c0 = c0_ref[...][0]                           # (cap,)
-        c1 = c1_ref[...][0]
-        reach = reach_ref[...][0]
-        sx = q0[:, None] - c0[None, :]
-        sy = q1[:, None] - c1[None, :]
+        sx = q0 - c0_ref[...]                         # (bQ, cap)
+        sy = q1 - c1_ref[...]
         d2 = sx * sx + sy * sy
-        thr = r[:, None] + reach[None, :]
+        thr = r + reach_ref[...]
         hit = (thr >= 0.0) & (d2 <= thr * thr)
-        out_ref[...] = hit.astype(jnp.int8)[:, None, :]
+        out_ref[...] = hit.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -87,7 +89,7 @@ def sphere_hits(q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
     slot_reach : jnp.ndarray
         (n_cells, cap) f32 — per-slot reach (``-inf`` = pad slot).
     bq : int
-        Query rows per program.
+        Query rows per program (rounded to a tile the chip accepts).
     interpret : bool
         Run the Pallas interpreter (CPU validation) instead of compiling.
 
@@ -96,35 +98,38 @@ def sphere_hits(q0: jnp.ndarray, q1: jnp.ndarray, radius: jnp.ndarray,
     jnp.ndarray
         (Q, n_cells · cap) int8 flat hit table, cell-major — index it with
         ``CentroidGrid.slot_of`` to recover cluster order.
+
+    Notes
+    -----
+    Every per-cell operand gets a unit middle axis — (n_cells, 1, ·) with
+    the cell axis squeezed out of the block — so each block spans its
+    array's last two dimensions, as the chip requires. The kernel writes a
+    cell-major (n_cells, Q, cap) table, transposed here.
     """
     q = q0.shape[0]
     n_cells, cap = c0.shape
-    bq = min(bq, q)
+    bq = sublane_tile(q, bq)
     pad_q = (-q) % bq
-    if pad_q:
-        q0 = jnp.pad(q0, (0, pad_q))
-        q1 = jnp.pad(q1, (0, pad_q))
-        radius = jnp.pad(radius, (0, pad_q))
+    q0, q1, radius = (jnp.pad(x, (0, pad_q))[:, None]
+                      for x in (q0, q1, radius))
     qp = q + pad_q
+    col = pl.BlockSpec((bq, 1), lambda i, c: (i, 0))
+
+    def cell(width):
+        return pl.BlockSpec((None, 1, width), lambda i, c: (c, 0, 0))
 
     out = pl.pallas_call(
         _sphere_kernel,
         grid=(qp // bq, n_cells),
-        in_specs=[
-            pl.BlockSpec((bq,), lambda i, c: (i,)),
-            pl.BlockSpec((bq,), lambda i, c: (i,)),
-            pl.BlockSpec((bq,), lambda i, c: (i,)),
-            pl.BlockSpec((1, 4), lambda i, c: (c, 0)),
-            pl.BlockSpec((1,), lambda i, c: (c,)),
-            pl.BlockSpec((1, cap), lambda i, c: (c, 0)),
-            pl.BlockSpec((1, cap), lambda i, c: (c, 0)),
-            pl.BlockSpec((1, cap), lambda i, c: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((bq, 1, cap), lambda i, c: (i, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((qp, n_cells, cap), jnp.int8),
+        in_specs=[col, col, col, cell(4), cell(1), cell(cap), cell(cap),
+                  cell(cap)],
+        out_specs=pl.BlockSpec((None, bq, cap), lambda i, c: (c, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_cells, qp, cap), jnp.int32),
         interpret=interpret,
-    )(q0, q1, radius, boxes, cell_reach, c0, c1, slot_reach)
-    return out[:q].reshape(q, n_cells * cap)
+    )(q0, q1, radius, boxes[:, None], cell_reach[:, None, None],
+      c0[:, None], c1[:, None], slot_reach[:, None])
+    return jnp.transpose(out[:, :q], (1, 0, 2)).reshape(
+        q, n_cells * cap).astype(jnp.int8)
 
 
 @jax.jit

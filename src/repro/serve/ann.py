@@ -106,7 +106,7 @@ class AnnServeEngine:
     FUSED_RERANK_MULT = 32
 
     def __init__(self, index: JunoIndexData | MutableJunoIndex, *,
-                 metric: str = "l2", impl: str = "ref",
+                 metric: str = "l2", impl: str | None = None,
                  thres_scale: float = 1.0, side_capacity: int = 256,
                  batch_buckets: tuple[int, ...] | None = None,
                  fused: bool = False, fused3: bool | None = None,
@@ -122,8 +122,10 @@ class AnnServeEngine:
             The index to serve (a bare ``JunoIndexData`` is wrapped).
         metric : str
             "l2" | "ip".
-        impl : str
-            "ref" | "pallas" — forwarded to the search kernels.
+        impl : str, optional
+            "ref" | "pallas" — forwarded to the search kernels. ``None``
+            follows the platform: "pallas" on a TPU, "ref" elsewhere
+            (``kernels.ops.default_impl``).
         thres_scale : float
             Selectivity-threshold multiplier forwarded to search.
         side_capacity : int
@@ -138,7 +140,7 @@ class AnnServeEngine:
         fused3 : bool, optional
             Three-stage dispatch override (``core.juno.search``): with
             ``fused=True`` and ``prefilter="rt"`` the engine serves the
-            single-residency RT→hit-count→ADC kernel by default;
+            three-stage RT→hit-count→ADC kernels by default;
             ``False`` forces the composed rt-mask + two-stage path
             (bit-identical ids/scores — the parity baseline the
             benchmarks gate against).
@@ -434,22 +436,24 @@ class AnnServeEngine:
 
     def _dispatch(self, qb, k, mode, nprobe, side):
         """Run one padded batch through the jitted search for its mode."""
-        rt_kw = {}
+        fn, kw = self.search_call(k, mode, nprobe, side)
+        return fn(self.index.data, qb, **kw)
+
+    def search_call(self, k, mode, nprobe, side):
+        """The jitted search a dispatch of this signature runs, and its
+        keyword arguments: ``fn(index.data, queries, **kw)``. Exposed so a
+        caller can ``fn.lower(...)`` exactly what the engine serves."""
+        kw = dict(k=k, nprobe=nprobe, metric=self.metric,
+                  thres_scale=self.thres_scale, impl=self.impl, side=side)
         if self.prefilter == "rt":
-            rt_kw = dict(prefilter="rt",
-                         rt_grid=self.index.ensure_rt_grid(metric=self.metric),
-                         rt_scale=self.rt_scale)
+            kw.update(prefilter="rt",
+                      rt_grid=self.index.ensure_rt_grid(metric=self.metric),
+                      rt_scale=self.rt_scale)
         if mode == "H2":
-            return _search_batch_two_stage(
-                self.index.data, qb, nprobe=nprobe, k=k, metric=self.metric,
-                thres_scale=self.thres_scale, impl=self.impl,
-                fused=self.fused, fused3=self.fused3,
-                rerank=self.FUSED_RERANK_MULT * k if self.fused else 0,
-                side=side, **rt_kw)
-        return _search_batch(
-            self.index.data, qb, nprobe=nprobe, k=k, mode=mode,
-            metric=self.metric, thres_scale=self.thres_scale,
-            impl=self.impl, side=side, **rt_kw)
+            return _search_batch_two_stage, dict(
+                kw, fused=self.fused, fused3=self.fused3,
+                rerank=self.FUSED_RERANK_MULT * k if self.fused else 0)
+        return _search_batch, dict(kw, mode=mode)
 
     def _observe_dispatch(self, k, mode, nprobe, bucket, n, empty_side):
         """Record per-dispatch registry metrics (obs is known non-None).

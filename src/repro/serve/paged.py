@@ -529,7 +529,7 @@ class PagedJunoIndex(MutableIndexBase):
     # ---- query -----------------------------------------------------------
     def search(self, queries, *, nprobe: int = 16, k: int = 10,
                mode: str = "H", metric: str = "l2",
-               thres_scale: float = 1.0, impl: str = "ref",
+               thres_scale: float = 1.0, impl: str | None = None,
                rerank: int = 0, fused: bool = False,
                fused3: bool | None = None,
                prefilter: str = "scan", rt_scale: float = 1.0):

@@ -166,8 +166,11 @@ def test_ensure_tuned_retunes_on_corrupt_cache(tmp_path, monkeypatch):
 # tuned vs default: launch parameters must not change results
 # ---------------------------------------------------------------------------
 def _problem():
+    """Q = 20 and P = 300: every candidate tiling splits the query axis
+    (padded tail) and each probe's points (padded lane tail) into several
+    blocks, and the candidates' tilings differ from one another."""
     rng = np.random.default_rng(0)
-    q, n_probe, p, s, e, cap_c = 5, 3, 24, 6, 16, 12
+    q, n_probe, p, s, e, cap_c = 20, 3, 300, 6, 16, 12
     lut = rng.standard_normal((q, n_probe, s, e)).astype(np.float32)
     table = rng.integers(-1, 2, (q, n_probe, s, e)).astype(np.int8)
     codes = rng.integers(0, e, (q, n_probe, p, s)).astype(np.uint8)
@@ -211,9 +214,6 @@ def test_tuned_configs_bit_identical_three_stage():
     lut, table, codes, valid, cap_c = _problem()
     rng = np.random.default_rng(1)
     g, cap, q, n_probe = 3, 8, lut.shape[0], lut.shape[1]
-    loxy = np.stack(np.meshgrid(np.arange(g), np.arange(g), indexing="ij"),
-                    -1).reshape(-1, 2) / g
-    boxes = np.concatenate([loxy, loxy + 1.0 / g], 1).astype(np.float32)
     c0 = rng.random((g * g, cap)).astype(np.float32)
     c1 = rng.random((g * g, cap)).astype(np.float32)
     reach = np.abs(rng.normal(0, 0.2, (g * g, cap))).astype(np.float32)
@@ -221,18 +221,16 @@ def test_tuned_configs_bit_identical_three_stage():
     args = (rng.random(q).astype(np.float32),
             rng.random(q).astype(np.float32),
             rng.random(q).astype(np.float32),
-            boxes, reach.max(1), c0, c1, reach,
+            c0, c1, reach,
             rng.integers(0, g * g * cap, (q, n_probe)).astype(np.int32))
-    base_h = fused_three_stage_host(
-        lut, table, codes, valid, args[0], args[1], args[2], args[5],
-        args[6], args[7], args[8], cap_c=cap_c, metric="l2")
+    base_h = fused_three_stage_host(lut, table, codes, valid, *args,
+                                    cap_c=cap_c, metric="l2")
     base_k = fused_three_stage(lut, table, codes, valid, *args,
                                cap_c=cap_c, metric="l2", interpret=True)
     for cfg in candidates("cpu") + candidates("tpu"):
-        h = fused_three_stage_host(
-            lut, table, codes, valid, args[0], args[1], args[2], args[5],
-            args[6], args[7], args[8], cap_c=cap_c, metric="l2",
-            topc_impl=cfg.topc_impl)
+        h = fused_three_stage_host(lut, table, codes, valid, *args,
+                                   cap_c=cap_c, metric="l2",
+                                   topc_impl=cfg.topc_impl)
         k = fused_three_stage(lut, table, codes, valid, *args, cap_c=cap_c,
                               metric="l2", bq=cfg.bq, bp=cfg.bp,
                               acc=cfg.acc_dtype, interpret=True)

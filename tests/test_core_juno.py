@@ -203,3 +203,60 @@ def test_search_returns_sorted_and_valid(small_l2):
     s, ids = search(idx, q, nprobe=8, k=20, mode="H")
     assert bool(jnp.all(ids >= 0)) and bool(jnp.all(ids < pts.shape[0]))
     assert bool(jnp.all(jnp.diff(s, axis=1) >= -1e-5))  # ascending L2
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_exact_topk_highest_precision(metric):
+    """Ground truth stays exact on a TPU: every dot product in the lowered
+    exact_topk program runs at Precision.HIGHEST (the default would round
+    f32 operands to bf16 on the MXU)."""
+    q = jnp.zeros((4, 16), jnp.float32)
+    pts = jnp.zeros((64, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda a, b: exact_topk(a, b, k=5, metric=metric, chunk=32))(q, pts)
+    text = str(jaxpr)
+    dots = text.count("dot_general")
+    assert dots >= 1
+    assert text.count("Precision.HIGHEST") >= dots
+
+
+def _padded_layout_loop(labels, n_clusters, cap):
+    """The point-at-a-time layout pass padded_layout vectorizes: each
+    cluster takes its first ``cap`` points in id order, overflow spills to
+    the emptiest non-full clusters (the reference the test holds it to)."""
+    point_ids = np.full((n_clusters, cap), -1, dtype=np.int32)
+    fill = np.zeros((n_clusters,), dtype=np.int64)
+    overflow = []
+    for pid, c in enumerate(labels):
+        if fill[c] < cap:
+            point_ids[c, fill[c]] = pid
+            fill[c] += 1
+        else:
+            overflow.append(pid)
+    if overflow:
+        oi = 0
+        for c in np.argsort(fill):
+            while fill[c] < cap and oi < len(overflow):
+                point_ids[c, fill[c]] = overflow[oi]
+                labels[overflow[oi]] = c
+                fill[c] += 1
+                oi += 1
+            if oi >= len(overflow):
+                break
+    return point_ids, labels
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_padded_layout_matches_loop(seed):
+    """Vectorized layout == the point-at-a-time pass, spills included."""
+    from repro.core.ivf import padded_layout
+    rng = np.random.default_rng(seed)
+    c = int(rng.integers(1, 24))
+    n = int(rng.integers(1, 600))
+    cap = max(int(rng.integers(1, 48)), -(-n // c))
+    w = rng.random(c) ** 4                  # skewed: forces overflow spills
+    labels = rng.choice(c, size=n, p=w / w.sum()).astype(np.int32)
+    got_ids, got_labels = padded_layout(labels.copy(), c, cap)
+    want_ids, want_labels = _padded_layout_loop(labels.copy(), c, cap)
+    np.testing.assert_array_equal(got_ids, want_ids)
+    np.testing.assert_array_equal(got_labels, want_labels)

@@ -29,6 +29,7 @@ from repro.kernels import ref
 from repro.kernels.fused_three_stage import (fused_three_stage,
                                              fused_three_stage_host)
 from repro.kernels.fused_two_stage import fused_two_stage
+from repro.kernels.onehot import lane_tile, sublane_tile
 
 pytestmark = pytest.mark.interpret
 
@@ -83,6 +84,13 @@ def _synth_grid(seed, n_cells_side, cap, q, n_probe):
                                    c0, c1, reach, slot_idx)))
 
 
+def _kargs(grid_args):
+    """The kernel's grid operands: the synthesizer's minus boxes and
+    cell_reach (the kernel tests only the probed slots, no cell walk)."""
+    q0, q1, radius, _, _, c0, c1, reach, slot_idx = grid_args
+    return q0, q1, radius, c0, c1, reach, slot_idx
+
+
 def _composed(lut, table, codes, valid, grid_args, cap_c, metric):
     """The replaced pipeline: interpret-mode sphere walk → _rt_probe_mask
     gather (slot_of lookup + probe-0 backstop) → interpret-mode fused
@@ -108,7 +116,7 @@ def _check_kernel(seed, q, n_probe, p, s, e, cap_c, metric, g=3, cap=8,
         lut, table, codes, valid, grid_args[0], grid_args[1], grid_args[2],
         grid_args[5], grid_args[6], grid_args[7], grid_args[8],
         cap_c=cap_c, metric=metric)
-    got = fused_three_stage(lut, table, codes, valid, *grid_args,
+    got = fused_three_stage(lut, table, codes, valid, *_kargs(grid_args),
                             cap_c=cap_c, metric=metric, interpret=True)
     g_counts, g_dist, g_cand, g_cdist, g_pok = (np.asarray(x) for x in got)
 
@@ -167,7 +175,7 @@ def test_fused3_probe0_backstop():
     grid_args = list(_synth_grid(9, 3, 8, 4, 3))
     grid_args[2] = jnp.full((4,), -1.0, jnp.float32)   # negative radius:
     # thr = r + reach < 0 for every slot (max reach < 1), so no hits
-    got = fused_three_stage(lut, table, codes, valid, *grid_args,
+    got = fused_three_stage(lut, table, codes, valid, *_kargs(grid_args),
                             cap_c=8, metric="l2", interpret=True)
     pok = np.asarray(got[4])
     np.testing.assert_array_equal(
@@ -224,8 +232,9 @@ def test_fused3_acc_dtype_invariance():
     from repro.kernels.fused_two_stage import ACC_DTYPES
     lut, table, codes, valid = _inputs(23, 5, 2, 16, 8, 16, 0.8)
     ga = _synth_grid(24, 3, 8, 5, 2)
-    outs = [fused_three_stage(lut, table, codes, valid, *ga, cap_c=10,
-                              metric="l2", acc=acc, interpret=True)
+    outs = [fused_three_stage(lut, table, codes, valid, *_kargs(ga),
+                              cap_c=10, metric="l2", acc=acc,
+                              interpret=True)
             for acc in ACC_DTYPES]
     c0, d0, i0, cd0, p0 = (np.asarray(x) for x in outs[0])
     for o in outs[1:]:
@@ -240,15 +249,27 @@ def test_fused3_acc_dtype_invariance():
 
 
 def test_fused3_block_size_invariance():
-    """Results must not depend on the (bQ, bP) tiling — with the extra
-    twist that bP changes how many point blocks share the grid axis with
-    the cells (different clamp overlap every time)."""
-    lut, table, codes, valid = _inputs(17, 6, 2, 24, 6, 16, 0.8)
-    ga = _synth_grid(18, 3, 8, 6, 2)
-    outs = [fused_three_stage(lut, table, codes, valid, *ga, cap_c=10,
-                              metric="l2", bq=bq, bp=bp, interpret=True)
-            for bq, bp in [(2, 8), (3, 24), (6, 12), (4, 4)]]
+    """Results must not depend on the (bQ, bP) tiling requested (the
+    kernel rounds each request to a tile the chip accepts). Q = 20 and
+    P = 300 keep the rounded tilings distinct: several query blocks with a
+    padded query tail, several point blocks per probe with a padded lane
+    tail, and the per-probe operands indexed across point blocks."""
+    q, p = 20, 300
+    tiles = [(8, 128), (16, 128), (8, 256), (24, 384)]
+    assert len({(sublane_tile(q, bq), lane_tile(bp))
+                for bq, bp in tiles}) == len(tiles)
+    lut, table, codes, valid = _inputs(17, q, 2, p, 6, 16, 0.8)
+    ga = _synth_grid(18, 3, 8, q, 2)
+    outs = [fused_three_stage(lut, table, codes, valid, *_kargs(ga),
+                              cap_c=10, metric="l2", bq=bq, bp=bp,
+                              interpret=True)
+            for bq, bp in tiles]
     c0, d0, i0, cd0, p0 = (np.asarray(x) for x in outs[0])
+    counts, _, cand, _, pok = _composed(lut, table, codes, valid, ga, 10,
+                                        "l2")
+    np.testing.assert_array_equal(c0, np.asarray(counts))
+    np.testing.assert_array_equal(i0, np.asarray(cand))
+    np.testing.assert_array_equal(p0, np.asarray(pok))
     for o in outs[1:]:
         c, d, i, cd, pk = (np.asarray(x) for x in o)
         np.testing.assert_array_equal(c0, c)

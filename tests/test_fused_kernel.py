@@ -28,6 +28,7 @@ from repro.kernels import ref
 from repro.kernels.fused_two_stage import (fused_two_stage,
                                            fused_two_stage_host)
 from repro.kernels.hit_count import hit_count
+from repro.kernels.onehot import lane_tile, sublane_tile
 from repro.kernels.pq_scan import pq_scan
 
 pytestmark = pytest.mark.interpret
@@ -88,17 +89,16 @@ def _check_kernel(seed, q, n_probe, p, s, e, cap_c, metric, valid_p=0.85):
     np.testing.assert_array_equal(g_cdist, want_cdist)
 
 
-# (Q, np, P, S, E, cap_c) — ragged Q (bQ padding), P not a multiple of the
-# default block (divisor fallback), prime P below and above the tile size
-# (the latter takes the point-padding path), S not a SLAB multiple
+# (Q, np, P, S, E, cap_c) — ragged Q (query-tail padding), P below and
+# above the 128-lane tile (lane-tail padding), S not a SLAB multiple
 SHAPES = [
     (3, 2, 17, 6, 8, 9),
     (5, 3, 12, 5, 16, 7),
-    (9, 2, 10, 12, 32, 20),    # Q=9 → bQ pad to 12
-    (6, 2, 31, 7, 8, 15),      # P=31 prime → bP=31
+    (9, 2, 10, 12, 32, 20),    # Q=9 → two 8-row blocks, query tail padded
+    (6, 2, 31, 7, 8, 15),      # P=31 → one 128-lane block, padded
     (2, 1, 8, 4, 8, 50),       # cap_c > W → clamped to W
     (1, 4, 13, 3, 4, 5),       # single query
-    (4, 2, 131, 5, 8, 20),     # P=131 prime > 128 → padded to bP=128 tiles
+    (4, 2, 131, 5, 8, 20),     # P=131 → two 128-lane blocks per probe
 ]
 
 
@@ -113,6 +113,15 @@ def test_fused_matches_composed_kernels(shape, metric):
 def test_fused_edge_masks(metric, valid_p):
     """All-pruned (every point invalid) and all-valid survivor masks."""
     _check_kernel(11, 4, 2, 16, 8, 8, 12, metric, valid_p=valid_p)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_fused_lane_padding_never_candidate(metric):
+    """Fewer valid points than cap_c: cand takes the valid points, then
+    invalid ones index-ascending, and never a lane-padding slot. P = 131
+    pads each probe to two 128-lane blocks, so probe 0's padding sits
+    between real points in the padded flat layout."""
+    _check_kernel(5, 3, 2, 131, 5, 8, 200, metric, valid_p=0.02)
 
 
 def test_fused_all_pruned_sentinels():
@@ -187,12 +196,23 @@ def test_fused_block_size_invariance():
     """Results must not depend on the (bQ, bP) tiling — pure BlockSpec
     property, mirroring test_kernels.test_block_size_invariance. Integer
     outputs (counts, cand) are bit-equal; f32 ADC totals may differ by
-    accumulation order across tile shapes, so they get a tight allclose."""
-    lut, table, codes, valid = _inputs(17, 6, 2, 24, 6, 16, 0.8)
+    accumulation order across tile shapes, so they get a tight allclose.
+
+    Q = 20 and P = 300 keep the tilings distinct after the kernel rounds
+    them to chip tiles: several query blocks with a padded query tail, and
+    several point blocks per probe with a padded lane tail."""
+    q, p = 20, 300
+    tiles = [(8, 128), (16, 128), (8, 256), (24, 384)]
+    assert len({(sublane_tile(q, bq), lane_tile(bp))
+                for bq, bp in tiles}) == len(tiles)
+    lut, table, codes, valid = _inputs(17, q, 2, p, 6, 16, 0.8)
     outs = [fused_two_stage(lut, table, codes, valid, cap_c=10, metric="l2",
                             bq=bq, bp=bp, interpret=True)
-            for bq, bp in [(2, 8), (3, 24), (6, 12), (4, 4)]]
+            for bq, bp in tiles]
     c0, d0, i0, cd0 = (np.asarray(x) for x in outs[0])
+    counts, _, _, cand = _composed(lut, table, codes, valid, 10, "l2")
+    np.testing.assert_array_equal(c0, np.asarray(counts))
+    np.testing.assert_array_equal(i0, np.asarray(cand))
     for o in outs[1:]:
         c, d, i, cd = (np.asarray(x) for x in o)
         np.testing.assert_array_equal(c0, c)

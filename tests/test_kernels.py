@@ -95,13 +95,13 @@ def test_pq_scan_batched_leading_dims():
 def test_block_size_invariance():
     """Result must not depend on the BlockSpec tiling — pure tiling property."""
     key = jax.random.PRNGKey(3)
-    s, e, p = 16, 128, 192
+    s, e, p = 16, 128, 300      # three, two and one lane blocks, padded
     lut = jax.random.normal(key, (s, e))
     codes = jax.random.randint(jax.random.fold_in(key, 1), (p, s), 0, e
                                ).astype(jnp.uint8)
     valid = jnp.ones((p,), bool)
     outs = [pq_scan(lut, codes, valid, bp=bp, interpret=True)
-            for bp in (32, 64, 192)]
+            for bp in (128, 256, 384)]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    rtol=1e-6, atol=1e-6)
@@ -156,10 +156,14 @@ def test_slab_onehot_dot_dtypes(batched):
                                -1, 2).astype(jnp.int8)
     tab_f = jax.random.normal(jax.random.fold_in(key, 2), (*lead, s, e))
 
-    got_i = ops.slab_onehot_dot(codes, tab_i.astype(jnp.int32), n_entries=e,
-                                out_dtype=jnp.int32)
-    got_f = ops.slab_onehot_dot(codes, tab_f, n_entries=e,
-                                out_dtype=jnp.float32)
+    def onehot(tab, out_dtype):
+        # the helper takes one query row: codes (P, S), tab (S, E) → (1, P)
+        fn = lambda c, t: ops.slab_onehot_dot(  # noqa: E731
+            c, t, n_entries=e, out_dtype=out_dtype)[0]
+        return jax.vmap(fn)(codes, tab) if batched else fn(codes, tab)
+
+    got_i = onehot(tab_i.astype(jnp.int32), jnp.int32)
+    got_f = onehot(tab_f, jnp.float32)
     assert got_i.dtype == jnp.int32
     assert got_f.dtype == jnp.float32
 
@@ -176,8 +180,7 @@ def test_slab_onehot_dot_dtypes(batched):
 
     # f32 accumulation of small-int tables is still exact (the fused kernel
     # relies on this to share one one-hot between both stages)
-    got_fi = ops.slab_onehot_dot(codes, tab_i.astype(jnp.float32),
-                                 n_entries=e, out_dtype=jnp.float32)
+    got_fi = onehot(tab_i.astype(jnp.float32), jnp.float32)
     np.testing.assert_array_equal(np.asarray(got_fi).astype(np.int32),
                                   np.asarray(want_i))
 
