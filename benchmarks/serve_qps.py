@@ -123,6 +123,13 @@ REQUEST_MIX = [
 ]
 
 
+def _latency_pcts(completed) -> dict:
+    """p50/p95/p99 of submit → done seconds over completed requests."""
+    lats = sorted(r.latency for r in completed)
+    return {name: lats[min(len(lats) - 1, int(p * len(lats)))]
+            for name, p in (("p50", 0.5), ("p95", 0.95), ("p99", 0.99))}
+
+
 def _make_trace(queries: np.ndarray, n_requests: int):
     trace, pos = [], 0
     for r in range(n_requests):
@@ -218,7 +225,7 @@ def run(dataset: str = "deep", n_requests: int = 96, insert_every: int = 12,
             ins_pos += insert_batch
     t_eng = time.perf_counter() - t0
     eng_qps = total_q / t_eng
-    lat = engine.latency_stats()
+    lat = _latency_pcts(engine.completed)
 
     common.emit("serve_qps.baseline_single_shot", t_base / n_requests * 1e6,
                 f"qps={base_qps:.0f}")
@@ -628,7 +635,7 @@ def run_freshness(n_cycles: int = 8, waves_per_cycle: int = 8) -> dict:
     eng.completed.clear()
     for _ in range(2 * waves_per_cycle):
         wave()
-    steady = eng.latency_stats()
+    steady = _latency_pcts(eng.completed)
     steady_p99 = steady["p99"]
 
     # --- the soak: n_cycles full spill -> promote -> fold cycles ---------
@@ -647,7 +654,7 @@ def run_freshness(n_cycles: int = 8, waves_per_cycle: int = 8) -> dict:
         eng.delete(retire(int(mid.side.capacity)))
         for _ in range(waves_per_cycle - half):
             wave()                           # folds drain between ticks
-        lat = eng.latency_stats()
+        lat = _latency_pcts(eng.completed)
         cycles.append({"p99": lat["p99"], "p50": lat["p50"],
                        "minor_gen": mid._minor_gen,
                        "delta_fill": mid.delta_fill})

@@ -454,15 +454,16 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
     m = index.codebook.sub_dim
     c_budget = rerank or 4 * k
 
-    if metric == "l2":
-        res = q[:, None, :] - index.ivf.centroids[cids]
-        qsub = res.reshape(nq, nprobe, -1, m)
-        probe_base = jnp.zeros((nq, nprobe), jnp.float32)
-    else:
-        qsub = jnp.broadcast_to(
-            q.reshape(nq, 1, -1, m), (nq, nprobe, q.shape[1] // m, m))
-        probe_base = base
-    tau = density_lib.predict_threshold(index.density, qsub, thres_scale)
+    with jax.named_scope("search.lut"):
+        if metric == "l2":
+            res = q[:, None, :] - index.ivf.centroids[cids]
+            qsub = res.reshape(nq, nprobe, -1, m)
+            probe_base = jnp.zeros((nq, nprobe), jnp.float32)
+        else:
+            qsub = jnp.broadcast_to(
+                q.reshape(nq, 1, -1, m), (nq, nprobe, q.shape[1] // m, m))
+            probe_base = base
+        tau = density_lib.predict_threshold(index.density, qsub, thres_scale)
 
     use_fused3 = fused and prefilter == "rt" and fused3 is not False
     if prefilter == "rt" and not use_fused3:
@@ -471,20 +472,22 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
         valid = valid & probe_ok[..., None]
 
     from repro.kernels import ops as kops
-    if impl == "pallas":
-        mlut, table = kops.build_selective_lut(
-            qsub, index.codebook.entries, index.codebook.entry_sq, tau,
-            metric=metric)
-    else:
-        lut, mask = lut_lib.build_lut(qsub, index.codebook, tau,
-                                      metric=metric)
-        mlut = lut_lib.masked_lut(lut, mask, tau, metric=metric)
-        if metric == "l2":
-            table = lut_lib.hit_tables(lut, mask, tau, mode="reward_penalty",
-                                       metric="l2")
+    with jax.named_scope("search.lut"):
+        if impl == "pallas":
+            mlut, table = kops.build_selective_lut(
+                qsub, index.codebook.entries, index.codebook.entry_sq, tau,
+                metric=metric)
         else:
-            table = lut_lib.hit_tables_ip(lut, index.codebook.entry_sq, tau,
-                                          mode="reward_penalty")
+            lut, mask = lut_lib.build_lut(qsub, index.codebook, tau,
+                                          metric=metric)
+            mlut = lut_lib.masked_lut(lut, mask, tau, metric=metric)
+            if metric == "l2":
+                table = lut_lib.hit_tables(lut, mask, tau,
+                                           mode="reward_penalty",
+                                           metric="l2")
+            else:
+                table = lut_lib.hit_tables_ip(lut, index.codebook.entry_sq,
+                                              tau, mode="reward_penalty")
 
     p = codes.shape[2]
     cap = min(c_budget, nprobe * p)
@@ -495,74 +498,80 @@ def _score_probed_two_stage(index: JunoIndexData, q: jnp.ndarray,
         # backstopped), then counts, thresholds and compacts as the fused
         # two-stage scan does — no HBM hit table, no host mask round trip
         from repro import rt as rt_lib
-        radius = rt_lib.query_radius(rt_grid, tau[:, 0, :], rt_scale)
-        qp2 = q @ rt_grid.proj                                   # (Q, 2)
-        gcids = cids if rt_offset is None else cids + rt_offset
-        slot_idx = jnp.take(rt_grid.slot_of, gcids)              # (Q, np)
-        _, _, cand, exact, probe_ok = kops.fused_three_stage_scan(
-            mlut, table, codes, valid, qp2[:, 0], qp2[:, 1], radius,
-            rt_grid.cell_c0, rt_grid.cell_c1, rt_grid.slot_reach, slot_idx,
-            cap_c=cap, metric=metric, impl=impl)
+        with jax.named_scope("search.hit_count"):
+            radius = rt_lib.query_radius(rt_grid, tau[:, 0, :], rt_scale)
+            qp2 = q @ rt_grid.proj                               # (Q, 2)
+            gcids = cids if rt_offset is None else cids + rt_offset
+            slot_idx = jnp.take(rt_grid.slot_of, gcids)          # (Q, np)
+            _, _, cand, exact, probe_ok = kops.fused_three_stage_scan(
+                mlut, table, codes, valid, qp2[:, 0], qp2[:, 1], radius,
+                rt_grid.cell_c0, rt_grid.cell_c1, rt_grid.slot_reach,
+                slot_idx, cap_c=cap, metric=metric, impl=impl)
         valid = valid & probe_ok[..., None]
-        cand_probe = cand // p                                   # (Q, C)
-        cand_valid = jnp.take_along_axis(valid.reshape(nq, -1), cand, axis=1)
-        cand_ids = jnp.take_along_axis(ids.reshape(nq, -1), cand, axis=1)
     elif fused:
         # both stages in one fused scan: counts, survivor threshold,
         # compacted top-C candidates + their ADC totals
-        _, _, cand, exact = kops.fused_two_stage_scan(
-            mlut, table, codes, valid, cap_c=cap, metric=metric, impl=impl)
-        cand_probe = cand // p                                   # (Q, C)
-        cand_valid = jnp.take_along_axis(valid.reshape(nq, -1), cand, axis=1)
-        cand_ids = jnp.take_along_axis(ids.reshape(nq, -1), cand, axis=1)
+        with jax.named_scope("search.hit_count"):
+            _, _, cand, exact = kops.fused_two_stage_scan(
+                mlut, table, codes, valid, cap_c=cap, metric=metric,
+                impl=impl)
     else:
-        if impl == "pallas":
-            counts = kops.hit_count_scan(table, codes, valid)
-        else:
-            counts = jax.vmap(jax.vmap(scan_lib.hit_count_scan))(table, codes,
-                                                                 valid)
+        with jax.named_scope("search.hit_count"):
+            if impl == "pallas":
+                counts = kops.hit_count_scan(table, codes, valid)
+            else:
+                counts = jax.vmap(jax.vmap(scan_lib.hit_count_scan))(
+                    table, codes, valid)
 
         # stage 1: top-C candidates by hit count (int32, cheap)
-        flat_counts = counts.reshape(nq, -1)
-        _, cand = jax.lax.top_k(flat_counts, cap)
-        cand_probe = cand // p                                   # (Q, C)
+        with jax.named_scope("search.top_c"):
+            flat_counts = counts.reshape(nq, -1)
+            _, cand = jax.lax.top_k(flat_counts, cap)
 
-        # stage 2: exact ADC only on survivors
-        cand_codes = jnp.take_along_axis(
-            codes.reshape(nq, -1, codes.shape[-1]), cand[..., None], axis=1)
-        s_idx = jnp.arange(mlut.shape[2])[None, None, :]
-        vals = mlut[jnp.arange(nq)[:, None, None], cand_probe[..., None],
-                    s_idx, cand_codes.astype(jnp.int32)]         # (Q, C, S)
-        exact = jnp.sum(vals, axis=-1)
+    # stage 2: exact ADC only on survivors (the fused scans computed it)
+    with jax.named_scope("search.rerank"):
+        cand_probe = cand // p                                   # (Q, C)
+        if not fused:
+            cand_codes = jnp.take_along_axis(
+                codes.reshape(nq, -1, codes.shape[-1]), cand[..., None],
+                axis=1)
+            s_idx = jnp.arange(mlut.shape[2])[None, None, :]
+            vals = mlut[jnp.arange(nq)[:, None, None], cand_probe[..., None],
+                        s_idx, cand_codes.astype(jnp.int32)]     # (Q, C, S)
+            exact = jnp.sum(vals, axis=-1)
         cand_valid = jnp.take_along_axis(valid.reshape(nq, -1), cand, axis=1)
         cand_ids = jnp.take_along_axis(ids.reshape(nq, -1), cand, axis=1)
-    if metric == "ip":
-        exact = exact + jnp.take_along_axis(probe_base, cand_probe, axis=1)
-    if side is not None:
-        # side points bypass stage 1 (the buffer is tiny) and join the exact
-        # rerank pool directly, scored identically to in-cluster survivors —
-        # including (under prefilter="rt") the probe's sphere-test verdict
-        tot, probe, ok = _side_gather(mlut, cids, side)
-        if prefilter == "rt":
-            ok = ok & jnp.take_along_axis(probe_ok, probe, axis=1)
         if metric == "ip":
-            tot = tot + jnp.take_along_axis(probe_base, probe, axis=1)
-        exact = jnp.concatenate(
-            [exact, jnp.where(ok, tot, -jnp.inf if metric == "ip" else jnp.inf)],
-            axis=1)
-        cand_valid = jnp.concatenate([cand_valid, ok], axis=1)
-        cand_ids = jnp.concatenate(
-            [cand_ids, jnp.broadcast_to(side.ids[None], (nq, side.capacity))],
-            axis=1)
-    if metric == "ip":
-        exact = jnp.where(cand_valid, exact, -jnp.inf)
-        sel_s, sel = jax.lax.top_k(exact, k)
-        out_scores = sel_s
-    else:
-        exact = jnp.where(cand_valid, exact, jnp.inf)
-        sel_s, sel = jax.lax.top_k(-exact, k)
-        out_scores = -sel_s
-    out_ids = jnp.take_along_axis(cand_ids, sel, axis=1)
+            exact = exact + jnp.take_along_axis(probe_base, cand_probe,
+                                                axis=1)
+        if side is not None:
+            # side points bypass stage 1 (the buffer is tiny) and join the
+            # exact rerank pool directly, scored identically to in-cluster
+            # survivors — including (under prefilter="rt") the probe's
+            # sphere-test verdict
+            tot, probe, ok = _side_gather(mlut, cids, side)
+            if prefilter == "rt":
+                ok = ok & jnp.take_along_axis(probe_ok, probe, axis=1)
+            if metric == "ip":
+                tot = tot + jnp.take_along_axis(probe_base, probe, axis=1)
+            exact = jnp.concatenate(
+                [exact, jnp.where(ok, tot,
+                                  -jnp.inf if metric == "ip" else jnp.inf)],
+                axis=1)
+            cand_valid = jnp.concatenate([cand_valid, ok], axis=1)
+            cand_ids = jnp.concatenate(
+                [cand_ids,
+                 jnp.broadcast_to(side.ids[None], (nq, side.capacity))],
+                axis=1)
+        if metric == "ip":
+            exact = jnp.where(cand_valid, exact, -jnp.inf)
+            sel_s, sel = jax.lax.top_k(exact, k)
+            out_scores = sel_s
+        else:
+            exact = jnp.where(cand_valid, exact, jnp.inf)
+            sel_s, sel = jax.lax.top_k(-exact, k)
+            out_scores = -sel_s
+        out_ids = jnp.take_along_axis(cand_ids, sel, axis=1)
     return out_scores, out_ids
 
 
@@ -581,12 +590,21 @@ def _search_batch_two_stage(index: JunoIndexData, queries: jnp.ndarray, *,
     two-stage scoring tail (:func:`_score_probed_two_stage`). Returns
     (scores (Q, k), ids (Q, k)); see the tail's docstring for the fused
     and composed candidate-selection semantics.
+
+    Each stage runs under a ``jax.named_scope`` (``search.stage_a``,
+    ``search.gather``, ``search.lut``, ``search.hit_count``,
+    ``search.top_c``, ``search.rerank``), which names it in the device
+    ops' ``op_name`` metadata and nowhere else: a profiler trace is read
+    by stage, and the compiled program is the same.
     """
     q = queries.astype(jnp.float32)
-    base, cids = filter_clusters(q, index.ivf, nprobe=nprobe, metric=metric)
-    codes = index.cluster_codes[cids]                            # (Q,np,P,S)
-    valid = index.ivf.valid[cids]
-    ids = index.ivf.point_ids[cids]
+    with jax.named_scope("search.stage_a"):
+        base, cids = filter_clusters(q, index.ivf, nprobe=nprobe,
+                                     metric=metric)
+    with jax.named_scope("search.gather"):
+        codes = index.cluster_codes[cids]                        # (Q,np,P,S)
+        valid = index.ivf.valid[cids]
+        ids = index.ivf.point_ids[cids]
     return _score_probed_two_stage(
         index, q, base, cids, codes, valid, ids, k=k, metric=metric,
         thres_scale=thres_scale, rerank=rerank, impl=impl, fused=fused,

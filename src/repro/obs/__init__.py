@@ -16,7 +16,9 @@ counters, engine timestamps) behind three primitives:
   exact-rerank :class:`RecallProbe` feeding online ``recall@k`` gauges
   per recall tier (``repro.obs.recall``).
 
-The package is numpy + stdlib only — importable without jax — and all
+The package is numpy + stdlib only — importable without jax, which it
+imports on first use to make each live span a ``jax.profiler`` span too
+(``repro.obs.trace.annotation``) — and all
 instrumentation is host-side: enabling it never adds jit arguments,
 never widens the engine's signature lattice, and leaves served ids and
 scores bit-identical (pinned by ``tests/test_obs.py``). Subsystems

@@ -20,14 +20,44 @@ Completed spans land in a bounded ring buffer (oldest dropped,
 ``dropped`` counts), exportable as JSONL events alongside the metrics
 registry (``repro.obs.export``). Spans are appended on CLOSE, so buffer
 order is end-time order; nesting is reconstructed from ``parent_id``.
+
+Every live span is also a profiler span: :meth:`Tracer.span` opens
+:func:`annotation` (``jax.profiler.TraceAnnotation``) around it, so while
+a profiler is recording the span lands on the device trace's clock with
+the same name, attributes and nesting. Retro-recorded spans
+(:meth:`Tracer.record`) stay in the ring buffer only: the profiler takes
+no event after the fact.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Iterator, Optional
+
+
+@functools.cache
+def _trace_annotation():
+    """jax's ``TraceAnnotation`` class, or None where jax cannot be imported
+    (imported on first use: this package stays importable without jax)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def annotation(name: str, **attrs):
+    """A profiler span named ``name``: ``jax.profiler.TraceAnnotation``
+    with ``attrs`` (those that are not None) as its arguments, or a no-op
+    context where jax cannot be imported. It records only while a
+    profiler is recording; otherwise it costs one check."""
+    cls = _trace_annotation()
+    if cls is None:
+        return contextlib.nullcontext()
+    return cls(name, **{k: v for k, v in attrs.items() if v is not None})
 
 
 @dataclasses.dataclass
@@ -97,6 +127,9 @@ class Tracer:
              **attrs) -> Iterator[Span]:
         """Open a live span nested under :attr:`current`; closes on exit.
 
+        The span is also a profiler span (:func:`annotation`) of the same
+        name and attributes, ``trace_id`` among them.
+
         Parameters
         ----------
         name : str
@@ -110,14 +143,15 @@ class Tracer:
         parent = self.current
         if trace_id is None and parent is not None:
             trace_id = parent.trace_id
-        s = self._new(name, parent.span_id if parent else None, trace_id,
-                      time.perf_counter(), attrs)
-        self._stack.append(s)
-        try:
-            yield s
-        finally:
-            self._stack.pop()
-            self._close(s, time.perf_counter())
+        with annotation(name, trace_id=trace_id, **attrs):
+            s = self._new(name, parent.span_id if parent else None, trace_id,
+                          time.perf_counter(), attrs)
+            self._stack.append(s)
+            try:
+                yield s
+            finally:
+                self._stack.pop()
+                self._close(s, time.perf_counter())
 
     def record(self, name: str, t_start: float, t_end: float, *,
                trace_id: Optional[str] = None,
@@ -126,7 +160,8 @@ class Tracer:
 
         Used for segments whose boundaries were stamped before the
         tracer saw them — a request's submit→batch queue wait, a fleet
-        request's arrival→done lifetime. ``parent`` defaults to
+        request's arrival→done lifetime; it reaches the ring buffer only,
+        not the profiler (see the module docstring). ``parent`` defaults to
         :attr:`current` (the open span at call time), so retro-stamped
         spans still nest under the tick that completed them; like
         :meth:`span`, an omitted ``trace_id`` is inherited from the

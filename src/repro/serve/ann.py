@@ -36,7 +36,6 @@ fixed-shape argument).
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import time
 from typing import Optional
@@ -47,6 +46,7 @@ import numpy as np
 from repro.core.juno import (JunoIndexData, MutableIndexBase,
                              MutableJunoIndex, _search_batch,
                              _search_batch_two_stage)
+from repro.obs.trace import annotation
 
 
 @dataclasses.dataclass
@@ -172,6 +172,8 @@ class AnnServeEngine:
             creates a fresh bundle. Instrumentation is host-side only:
             no jit argument changes, no new signatures, bit-identical
             results (pinned by tests/test_obs.py). Default None = off.
+            The engine's live spans reach a recording profiler
+            (``jax.profiler``) with or without a bundle.
         """
         # any MutableIndexBase works as the served index: the sharded
         # DistributedMutableIndex flows through here too (the fleet layer's
@@ -234,9 +236,11 @@ class AnnServeEngine:
                       "swaps": 0, "signatures": collections.Counter()}
 
     def _span(self, name: str, trace_id: str = None, **attrs):
-        """Tracer span context when obs is on; no-op context otherwise."""
+        """A tracer span (also a profiler span) when obs is on; otherwise the
+        bare profiler span, so a recording profiler sees the engine's spans
+        with or without ``obs``."""
         if self.obs is None:
-            return contextlib.nullcontext()
+            return annotation(name, trace_id=trace_id, **attrs)
         return self.obs.tracer.span(name, trace_id=trace_id, **attrs)
 
     # ---- request plane ---------------------------------------------------
@@ -360,33 +364,40 @@ class AnnServeEngine:
             return self._step_inner()
 
     def _step_inner(self) -> int:
-        """One tick's pick → dispatch → merge body (inside the tick span)."""
-        sig = self.route(self.queue[0])
-        max_rows = self.batch_buckets[-1]
-        # one linear pass: pick head-signature requests FIFO until the batch
-        # budget closes; everything else keeps its order for later ticks
-        picked, rest, rows, closed = [], [], 0, False
-        for req in self.queue:
-            if closed or self.route(req) != sig:
-                rest.append(req)
-                continue
-            if picked and rows + req.queries.shape[0] > max_rows:
-                closed = True     # preserve FIFO within the signature
-                rest.append(req)
-                continue
-            picked.append(req)
-            rows += req.queries.shape[0]
-        self.queue = collections.deque(rest)
-        t_batch = time.perf_counter()   # batch formed; queue wait ends here
+        """One tick's pick → dispatch → merge body (inside the tick span).
 
-        k, mode, nprobe = sig
-        batch = np.concatenate([r.queries for r in picked], axis=0)
-        # an empty delta tier contributes nothing: drop the argument so the
-        # jitted program skips side scoring entirely (side=None and side≠None
-        # are separate traces; crossing over costs one compile, not a
-        # rebuild). With the LSM tiers enabled this is the combined
-        # fixed-capacity L0 ⊕ minors view, so merge cycles never retrace.
-        side = self.index.delta_view()
+        Each dispatch splits into ``engine.launch`` (host-to-device copy and
+        the jitted call) and ``engine.readback`` (waiting for the device and
+        copying the answers back), so an idle device is pinned on one.
+        """
+        with self._span("engine.pick"):
+            sig = self.route(self.queue[0])
+            max_rows = self.batch_buckets[-1]
+            # one linear pass: pick head-signature requests FIFO until the
+            # batch budget closes; everything else keeps its order for later
+            picked, rest, rows, closed = [], [], 0, False
+            for req in self.queue:
+                if closed or self.route(req) != sig:
+                    rest.append(req)
+                    continue
+                if picked and rows + req.queries.shape[0] > max_rows:
+                    closed = True     # preserve FIFO within the signature
+                    rest.append(req)
+                    continue
+                picked.append(req)
+                rows += req.queries.shape[0]
+            self.queue = collections.deque(rest)
+            t_batch = time.perf_counter()   # batch formed; queue wait ends
+
+            k, mode, nprobe = sig
+            batch = np.concatenate([r.queries for r in picked], axis=0)
+            # an empty delta tier contributes nothing: drop the argument so
+            # the jitted program skips side scoring entirely (side=None and
+            # side≠None are separate traces; crossing over costs one
+            # compile, not a rebuild). With the LSM tiers enabled this is
+            # the combined fixed-capacity L0 ⊕ minors view, so merge cycles
+            # never retrace.
+            side = self.index.delta_view()
         # a single request larger than the top bucket is served in top-bucket
         # chunks, so the jit-signature lattice stays closed for any request
         out_s, out_i = [], []
@@ -401,10 +412,12 @@ class AnnServeEngine:
                                        side is None)
             with self._span("engine.dispatch", mode=mode, k=k,
                             nprobe=nprobe, bucket=bucket, rows=n):
-                s, ids = self._dispatch(jnp.asarray(chunk), k, mode,
-                                        nprobe, side)
-                out_s.append(np.asarray(s)[:n])
-                out_i.append(np.asarray(ids)[:n])
+                with self._span("engine.launch"):
+                    s, ids = self._dispatch(jnp.asarray(chunk), k, mode,
+                                            nprobe, side)
+                with self._span("engine.readback"):
+                    out_s.append(np.asarray(s)[:n])
+                    out_i.append(np.asarray(ids)[:n])
             self.stats["padded_rows"] += bucket - n
             self.stats["signatures"][(k, mode, nprobe, bucket)] += 1
         # np.asarray above forced host materialization, so this bounds the
@@ -475,8 +488,7 @@ class AnnServeEngine:
     def _observe_served(self, picked, mode, rows):
         """Record per-request metrics + spans for one served tick.
 
-        Feeds the per-tier latency histograms (the documented registry
-        form of :meth:`latency_stats`), retro-stamps one
+        Feeds the per-tier latency histograms, retro-stamps one
         ``engine.enqueue`` span per request (submit → batch formation,
         i.e. queue wait), and hands a sample of requests to the recall
         probe when the bundle carries one.
@@ -613,29 +625,3 @@ class AnnServeEngine:
         if self.obs is not None:
             self.obs.registry.counter("juno_engine_swaps_total").inc()
         return self.generation
-
-    # ---- observability ---------------------------------------------------
-    def latency_stats(self) -> dict:
-        """Latency percentiles over completed requests (deprecated alias).
-
-        The ad-hoc key names here predate ``repro.obs``; the documented
-        form of the same signal is the registry's per-tier
-        ``juno_engine_request_seconds`` histogram (plus the
-        queue/compute/merge segment histograms), populated when the
-        engine is constructed with ``obs=``. This dict is kept as a
-        deprecated back-compat alias for existing callers.
-
-        Returns
-        -------
-        dict
-            ``{"n", "p50", "p95", "p99", "max"}`` in seconds (submit →
-            done), or ``{"n": 0}`` when nothing has completed. For
-            streaming accounting that survives ``completed`` truncation,
-            use the fleet layer's ``LatencyHistogram`` instead.
-        """
-        lats = sorted(r.latency for r in self.completed)
-        if not lats:
-            return {"n": 0}
-        pick = lambda p: lats[min(len(lats) - 1, int(p * len(lats)))]  # noqa: E731
-        return {"n": len(lats), "p50": pick(0.5), "p95": pick(0.95),
-                "p99": pick(0.99), "max": lats[-1]}

@@ -52,7 +52,7 @@ PUBLIC = [
     AnnServeEngine, AnnRequest, AnnServeEngine.__init__,
     AnnServeEngine.submit, AnnServeEngine.route, AnnServeEngine.step,
     AnnServeEngine.run, AnnServeEngine.insert, AnnServeEngine.delete,
-    AnnServeEngine.compact, AnnServeEngine.latency_stats,
+    AnnServeEngine.compact,
     # kernel dispatchers
     ops.build_selective_lut, ops.masked_adc_scan, ops.hit_count_scan,
     ops.fused_two_stage_scan, ops.fused_three_stage_scan,

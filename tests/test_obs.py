@@ -11,11 +11,22 @@ bookkeeping ONLY, so an engine with a live registry/tracer/recall-probe
 returns bit-identical ids and scores and compiles the identical jit
 signature lattice as an uninstrumented one, across the resident and
 paged tiers — and the deprecated dict-shaped stats remain consistent
-views of the registry series that replaced them.
+views of the registry series that replaced them. A third half pins the
+profiler twin of every live span: the tracer's spans, and the engine's
+spans with no bundle attached, reach ``jax.profiler``'s trace with their
+names, attributes and nesting, and the H2 program names its stages in
+the ops' ``op_name`` metadata.
 """
+import glob
+import os
+import re
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.build import ArtifactStore
 from repro.core import JunoConfig, build
@@ -300,26 +311,6 @@ def test_engine_spans_nest_under_ticks(obs_env):
     assert len(enq) == 4
 
 
-def test_latency_stats_is_registry_alias(obs_env):
-    _, q, cfg, idx, _ = obs_env
-    obs = Observability()
-    eng = AnnServeEngine(idx, obs=obs)
-    _mixed_wave(eng, q)
-    lat = eng.latency_stats()
-    hist = obs.registry.histogram("juno_engine_request_seconds", mode="H")
-    hist2 = obs.registry.histogram("juno_engine_request_seconds", mode="H2")
-    assert hist.n + hist2.n == lat["n"] == 4
-    # same observations on both sides: counts and the exact max agree;
-    # percentiles are upper-edge estimates in the registry form, so they
-    # may over-report the legacy exact-sorted quantile by at most one
-    # log-spaced bucket (and never under-report it)
-    merged = Histogram()
-    merged.merge(hist)
-    merged.merge(hist2)
-    assert merged.max == lat["max"]
-    assert lat["p50"] <= merged.percentile(0.75) <= lat["max"]
-
-
 def test_fleet_merged_registry_sums_replicas(obs_env):
     _, q, cfg, idx, _ = obs_env
     fleet = AnnServeFleet(idx, n_replicas=2, shards_per_replica=1, obs=True)
@@ -366,3 +357,106 @@ def test_observability_child_shares_tracer_and_probe():
     assert child.registry is not parent.registry
     child.registry.counter("juno_x_total").inc()
     assert "juno_x_total" not in parent.registry.snapshot()
+
+
+# ---------------------------------------------------------------------------
+# the profiler twin: spans and search stages on the device trace's clock
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler trace; its host events as (name, start
+    ns, end ns, stats dict), in start order."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    return sorted(((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats))
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events),
+                  key=lambda e: e[1])
+
+
+def test_tracer_span_reaches_the_profiler(tmp_path):
+    tracer = Tracer()
+
+    def work():
+        with tracer.span("engine.tick", trace_id="7", bucket=128):
+            with tracer.span("engine.dispatch", mode="H2"):
+                pass
+
+    evs = {e[0]: e for e in _profiled(tmp_path, work)}
+    tick, disp = evs["engine.tick"], evs["engine.dispatch"]
+    assert tick[3]["bucket"] == 128 and str(tick[3]["trace_id"]) == "7"
+    assert disp[3]["mode"] == "H2"
+    assert tick[1] <= disp[1] <= disp[2] <= tick[2]
+    # the ring buffer holds the same spans, nested the same way
+    rec = {sp.name: sp for sp in tracer.spans()}
+    assert rec["engine.dispatch"].parent_id == rec["engine.tick"].span_id
+
+
+def test_engine_without_obs_writes_its_spans_to_the_profiler(obs_env,
+                                                             tmp_path):
+    _, q, cfg, idx, _ = obs_env
+    eng = AnnServeEngine(idx, batch_buckets=(8,))
+    eng.submit(q[:5], k=10, mode="H2", nprobe=8)
+    eng.run()                                    # compile outside the trace
+
+    def work():
+        eng.submit(q[5:10], k=10, mode="H2", nprobe=8)
+        eng.run()
+
+    evs = [e for e in _profiled(tmp_path, work)
+           if e[0].startswith("engine.")]
+    assert [e[0] for e in evs] == [
+        "engine.tick", "engine.pick", "engine.dispatch", "engine.launch",
+        "engine.readback", "engine.merge"]
+    tick, pick, disp, launch, back, merge = evs
+
+    def inside(child, parent):
+        return parent[1] <= child[1] <= child[2] <= parent[2]
+
+    assert all(inside(e, tick) for e in (pick, disp, merge))
+    assert inside(launch, disp) and inside(back, disp)
+    assert pick[2] <= disp[1] and disp[2] <= merge[1]
+    assert launch[2] <= back[1]
+    assert disp[3]["bucket"] == 8 and disp[3]["rows"] == 5
+
+
+def test_h2_program_names_its_stages_in_op_metadata(obs_env):
+    _, q, cfg, idx, _ = obs_env
+    eng = AnnServeEngine(idx, batch_buckets=(8,))
+    fn, kw = eng.search_call(10, "H2", 8, None)
+    text = fn.lower(eng.index.data, q[:8], **kw).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    scopes = {c for n in names for c in n.split("/")}
+    assert {"search.stage_a", "search.gather", "search.lut",
+            "search.hit_count", "search.top_c", "search.rerank"} <= scopes
+
+
+def test_obs_imports_and_traces_without_jax():
+    code = """
+import sys
+class NoJax:
+    def find_spec(self, name, path=None, target=None):
+        if name == "jax" or name.startswith("jax."):
+            raise ImportError("jax is blocked")
+sys.meta_path.insert(0, NoJax())
+import repro.obs
+tracer = repro.obs.Tracer()
+with tracer.span("engine.tick", bucket=8):
+    pass
+assert [s.name for s in tracer.spans()] == ["engine.tick"]
+assert not any(m == "jax" or m.startswith("jax.") for m in sys.modules)
+"""
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
