@@ -53,7 +53,7 @@ def annotation(name: str, **attrs):
     """A profiler span named ``name``: ``jax.profiler.TraceAnnotation``
     with ``attrs`` (those that are not None) as its arguments, or a no-op
     context where jax cannot be imported. It records only while a
-    profiler is recording; otherwise it costs one check."""
+    profiler is recording; otherwise it costs a check and the call."""
     cls = _trace_annotation()
     if cls is None:
         return contextlib.nullcontext()
